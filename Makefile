@@ -23,8 +23,16 @@ test-fast:
 test:
 	$(PYTEST) -x -q -m ""
 
+# The full report.  By default the Pallas suites (roofline,
+# vmem_dispersion) run in the Pallas interpreter so that the report can be
+# regenerated on the CPU backend; their rows are then interpreter timings
+# (the roofline payload records `"interpret": true`).  On a TPU, run
+# `make bench BENCH_FLAGS=` to compile and time the kernels on the chip.
+BENCH_FLAGS ?= --interpret
+
 bench:
-	PYTHONPATH=$(PYTHONPATH) python -m benchmarks.run --json BENCH_core.json
+	PYTHONPATH=$(PYTHONPATH) python -m benchmarks.run --json BENCH_core.json \
+	  $(BENCH_FLAGS)
 
 # Schema guard: the full front door (suites, --kernels subsetting, schema-5
 # JSON with metric metadata) on a 2-kernel subset in a couple of minutes.
@@ -108,9 +116,11 @@ serve-smoke:
 # Roofline regression guard: the measured Pallas suite on the smoke grid
 # must record >0 rows and >0 dispatches with the per-point measured/model
 # payload present — the suite can never silently regress to 0 rows again.
+# It runs the kernels in the Pallas interpreter (--interpret) so that it
+# also works on the CPU backend.
 roofline-smoke:
 	PYTHONPATH=$(PYTHONPATH) python -m benchmarks.run \
-	  --json BENCH_roofline_smoke.json --max-events 120 roofline
+	  --json BENCH_roofline_smoke.json --max-events 120 --interpret roofline
 	PYTHONPATH=$(PYTHONPATH) python -c "import json; \
 	  r = json.load(open('BENCH_roofline_smoke.json'))['suites']['roofline']; \
 	  assert r['rows'] > 0 and r['dispatches'] > 0, r; \

@@ -2,11 +2,12 @@
 
 The suite times the three dispersed-accumulator schedules —
 ``matmul_grouped`` (working set W >= 1), ``matmul_dispersed`` (the W=0
-spill/fill extreme) and ``flash_attention`` — in interpret mode on CPU and
-natively on TPU/GPU (``ops._auto_interpret`` picks), and cross-checks every
-point against the closed-form ``hbm_traffic_model`` bytes: the instrumented
-traffic count (:mod:`repro.kernels.traffic`, walking the schedule's actual
-BlockSpec index maps) must agree with the model, and each row carries both
+spill/fill extreme) and ``flash_attention`` — compiled on the TPU, or in
+the Pallas interpreter when the caller passes ``interpret=True`` (the CPU
+smoke path) — and cross-checks every point against the closed-form
+``hbm_traffic_model`` bytes: the instrumented traffic count
+(:mod:`repro.kernels.traffic`, walking the schedule's actual BlockSpec
+index maps) must agree with the model, and each row carries both
 arithmetic-intensity columns plus a per-row ``model_agree`` flag.
 
 The accumulator working set ``W`` and the input precision (f32 / bf16 /
@@ -44,14 +45,14 @@ import jax.numpy as jnp
 from benchmarks import common
 from repro import api
 from repro.configs import SHAPES, get
-from repro.kernels import dispersed_gemm, flash_attention, ops, traffic
+from repro.kernels import dispersed_gemm, flash_attention, traffic
 from repro.launch import analytic
 
 RESULTS = os.path.join(os.path.dirname(__file__), "..", "results", "dryrun")
 
 # (m, k, n) GEMM cases and (b, h, s, d) attention cases, sized so the
-# interpret-mode sweep stays CPU-affordable; on a real TPU/GPU backend the
-# same axes time the compiled kernels.
+# interpret-mode sweep stays CPU-affordable; on the TPU the same axes time
+# the compiled kernels.
 GEMM_CASES = {"gemm_256x512x256": (256, 512, 256),
               "gemm_512x512x256": (512, 512, 256)}
 FLASH_CASES = {"attn_b1h2_s256_d64": (1, 2, 256, 64)}
@@ -172,8 +173,10 @@ def equal_vmem_points(m: int) -> list[tuple[int, int, int]]:
             if m % bm == 0 and (m // bm) % w == 0]
 
 
-def run_measured(smoke: bool = False, repeats: int = 3):
-    """Execute the measured suite.
+def run_measured(smoke: bool = False, repeats: int = 3,
+                 interpret: bool = False):
+    """Execute the measured suite (``interpret=True`` runs the kernels in
+    the Pallas interpreter, for the CPU backend).
 
     Returns ``(gemm_result, flash_result, rows)``: two labeled
     :class:`repro.api.SweepResult` grids (axes ``case`` x ``working_set``
@@ -181,7 +184,6 @@ def run_measured(smoke: bool = False, repeats: int = 3):
     metrics derived, plus the flat row list (including the equal-VMEM
     study rows, which vary ``block_m``/``block_k`` off the main grid).
     """
-    interpret = ops._auto_interpret()
     gemm_cases = SMOKE_GEMM_CASES if smoke else GEMM_CASES
     flash_cases = SMOKE_FLASH_CASES if smoke else FLASH_CASES
     w_axis = SMOKE_W_AXIS if smoke else W_AXIS
@@ -368,9 +370,10 @@ _HEADER = ["name", "us_per_call", "working_set", "precision",
            "counted_bytes", "model_bytes", "vmem_acc_bytes"]
 
 
-def main(max_events: int | None = None) -> list[dict]:
+def main(max_events: int | None = None,
+         interpret: bool = False) -> list[dict]:
     smoke = max_events is not None and max_events <= 5000
-    _, _, rows = run_measured(smoke=smoke)
+    _, _, rows = run_measured(smoke=smoke, interpret=interpret)
     common.emit(rows, _HEADER)
     for study in _LAST_EXTRA.get("equal_vmem", ()):
         print(f"# equal-VMEM {study['case']}: measured winner "

@@ -11,7 +11,9 @@ list (table3/fig4/fig5/fig6/fig8/pareto) to that subset; fixed-roster
 studies (fig2, policy_headroom, ablation_sensitivity, ...) run their own
 set and say so.  ``make bench-smoke`` uses it to guard the JSON schema
 cheaply.  ``--max-events N`` forwards the legacy truncation budget the
-same way.
+same way, and ``--interpret`` runs the Pallas suites (roofline,
+vmem_dispersion) in the Pallas interpreter — needed on the CPU backend,
+where the kernels cannot compile.
 
 ``--json PATH`` writes a versioned report (``schema: 6``): per-suite
 wall-clock, XLA compile AND dispatch counts (the fused engine compiles once
@@ -51,6 +53,7 @@ import time
 
 from repro import api, metrics, silicon
 from repro.core import simulator
+from repro.launch.cache import use_compile_cache
 
 SCHEMA_VERSION = 7
 
@@ -90,10 +93,12 @@ def _sweep_meta(history_slice: list[dict]) -> list[dict]:
             for h in history_slice]
 
 
-def _call_main(mod, kernels, max_events):
+def _call_main(mod, kernels, max_events, interpret):
     """Invoke a suite's main(), forwarding only the kwargs it accepts."""
     params = inspect.signature(mod.main).parameters
     kw = {}
+    if interpret and "interpret" in params:
+        kw["interpret"] = True
     if kernels:
         if "names" in params:
             kw["names"] = list(kernels)
@@ -117,6 +122,8 @@ def _pop_flag(args: list, flag: str):
 
 def main(argv=None) -> int:
     args = list(argv if argv is not None else sys.argv[1:])
+    interpret = "--interpret" in args
+    args = [a for a in args if a != "--interpret"]
     try:
         json_path = _pop_flag(args, "--json")
         kernels = _pop_flag(args, "--kernels")
@@ -140,6 +147,7 @@ def main(argv=None) -> int:
         print(f"error: unknown suite(s) {', '.join(unknown)}; "
               f"choose from: {', '.join(SUITES)}", file=sys.stderr)
         return 2
+    use_compile_cache()
     session = api.default_session()
     report = {"schema": SCHEMA_VERSION, "suites": {}, "kernels": {},
               "metrics": metrics.catalog(),
@@ -153,7 +161,7 @@ def main(argv=None) -> int:
         d0 = simulator.dispatch_count()
         h0 = len(session.history)
         ps0 = mod.perf_stats() if hasattr(mod, "perf_stats") else {}
-        rows = _call_main(mod, kernels, max_events)
+        rows = _call_main(mod, kernels, max_events, interpret)
         dt = time.time() - t0
         print(f"## {suite} done in {dt:.1f}s", flush=True)
         report["suites"][suite] = {

@@ -4,8 +4,8 @@ height/traffic trade-off (Fig 4's economics) at the VMEM<->HBM boundary.
 
 Numeric correctness of both schedules is covered by tests; this benchmark
 reports the closed-form traffic model on a training-shaped GEMM
-(M=8192 tokens x K=4096 x N=14336, granite-8b MLP) and a small timed
-interpret-mode run."""
+(M=8192 tokens x K=4096 x N=14336, granite-8b MLP) and a small numeric
+spot-check of the grouped kernel (``interpret=True`` on the CPU backend)."""
 
 from __future__ import annotations
 
@@ -19,7 +19,7 @@ from benchmarks import common
 from repro.kernels import ops
 
 
-def run() -> list[dict]:
+def run(interpret: bool = False) -> list[dict]:
     rows = []
     m, k, n = 8192, 4096, 14336
     for w in (1, 2, 4, 8, 16):
@@ -32,11 +32,12 @@ def run() -> list[dict]:
             ideal_gb=round(t["ideal"] / 1e9, 2),
             vmem_acc_mb=round(t["vmem_acc_bytes"] / 1e6, 2),
         ))
-    # small numeric spot-check (interpret mode)
+    # small numeric spot-check
     a = jax.random.normal(jax.random.PRNGKey(0), (256, 512), jnp.float32)
     b = jax.random.normal(jax.random.PRNGKey(1), (512, 256), jnp.float32)
     t0 = time.time()
-    got = ops.matmul(a, b, working_set=2, block_m=128, block_k=256)
+    got = ops.matmul(a, b, working_set=2, block_m=128, block_k=256,
+                     interpret=interpret)
     want = a @ b
     err = float(jnp.max(jnp.abs(got - want)))
     rows.append(dict(name="interpret_check", grouped_gb="", dispersed_gb="",
@@ -46,8 +47,8 @@ def run() -> list[dict]:
     return rows
 
 
-def main():
-    rows = run()
+def main(interpret: bool = False):
+    rows = run(interpret=interpret)
     common.emit(rows, ["name", "us_per_call", "grouped_gb", "dispersed_gb",
                        "ideal_gb", "vmem_acc_mb", "max_err"])
     return rows

@@ -1,4 +1,5 @@
-"""Pallas TPU kernels (validated in interpret mode on CPU; see ops.py)."""
+"""Pallas TPU kernels: compiled on the TPU, run with an explicit
+``interpret=True`` on the CPU backend (see ops.py)."""
 
 from repro.kernels import (dispersed_gemm, flash_attention, ops, ref,
                            rmsnorm, traffic)

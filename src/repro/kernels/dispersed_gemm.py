@@ -15,8 +15,14 @@ Two schedules expose the paper's trade-off:
     hierarchy.  VMEM cost grows linearly in W (the cVRF area analogue).
 
   * ``matmul_dispersed()`` — the W=0 extreme: every accumulator access
-    round-trips through HBM (grid (k, m) with the output block revisited
-    per k step), i.e. every "register access" is a spill+fill.
+    round-trips through HBM (grid (k, m); the f32 accumulator is an HBM
+    buffer aliased input->output, filled into VMEM and spilled back by
+    explicit DMAs on every step), i.e. every "register access" is a
+    spill+fill.
+
+Both ``pallas_call``s size their scoped-VMEM limit from their own block
+footprint (:func:`vmem_footprint`), so full-width model GEMMs compile
+instead of tripping the compiler's default limit.
 
 ``hbm_traffic_model`` gives the closed-form bytes for the roofline tables;
 ``grouped_schedule`` / ``dispersed_schedule`` expose the grids and the
@@ -37,6 +43,12 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import traffic
 
 ACC_BYTES = 4      # both schedules accumulate in f32
+
+# Scoped-VMEM budget: v5e has 128 MiB of physical VMEM per core; a kernel
+# may ask for at most this much, leaving room for the compiler's own use.
+VMEM_CAP_BYTES = 96 * 2**20
+# Added to every footprint for the compiler's internal scratch.
+VMEM_SLACK_BYTES = 2 * 2**20
 
 
 def _check_tiles(m: int, k: int, k2: int, *, block_m: int, block_k: int):
@@ -95,6 +107,52 @@ def _dispersed_maps():
     return a, b, o
 
 
+def vmem_footprint(schedule: str, m: int, n: int, k: int, *, block_m: int,
+                   block_k: int, working_set: int = 1,
+                   bytes_per_el: int = 2) -> dict[str, int]:
+    """VMEM bytes per buffer of one schedule's ``pallas_call``: the
+    double-buffered A/B (and, grouped, C) pipeline blocks, the f32
+    accumulator scratch, and the f32 product of one block step."""
+    block_m, block_k, nm, _ = _check_tiles(
+        m, k, k, block_m=block_m, block_k=block_k)
+    acc_tile = block_m * n * ACC_BYTES
+    parts = {"a": 2 * block_m * block_k * bytes_per_el,
+             "b": 2 * block_k * n * bytes_per_el}
+    if schedule == "grouped":
+        w, _ = _check_working_set(working_set, nm)
+        parts["c"] = 2 * block_m * n * bytes_per_el
+        parts["acc"] = w * acc_tile
+    elif schedule == "dispersed":
+        parts["acc"] = acc_tile
+    else:
+        raise ValueError(f"unknown schedule {schedule!r}")
+    parts["product"] = acc_tile
+    return parts
+
+
+def _compiler_params(name: str, parts: dict[str, int]) -> pltpu.CompilerParams:
+    """Scoped-VMEM limit derived from the kernel's block footprint; a shape
+    that cannot fit under :data:`VMEM_CAP_BYTES` is refused by name."""
+    need = sum(parts.values()) + VMEM_SLACK_BYTES
+    if need > VMEM_CAP_BYTES:
+        blocks = ", ".join(f"{p}={v / 2**20:.1f} MiB"
+                           for p, v in parts.items())
+        raise ValueError(
+            f"{name}: VMEM footprint {need / 2**20:.1f} MiB exceeds the "
+            f"{VMEM_CAP_BYTES / 2**20:.0f} MiB budget ({blocks}); lower "
+            f"block_k, block_m or working_set")
+    return pltpu.CompilerParams(vmem_limit_bytes=need)
+
+
+def _mxu_operand(ref):
+    """Floating operands feed the MXU at their own width (f32 accumulate);
+    integer operands are widened to f32 first."""
+    x = ref[...]
+    if jnp.issubdtype(x.dtype, jnp.floating):
+        return x
+    return x.astype(jnp.float32)
+
+
 def _grouped_kernel(a_ref, b_ref, o_ref, acc_scr, *, nk: int):
     ik = pl.program_id(1)
     iw = pl.program_id(2)
@@ -104,7 +162,7 @@ def _grouped_kernel(a_ref, b_ref, o_ref, acc_scr, *, nk: int):
         acc_scr[iw] = jnp.zeros_like(acc_scr[iw])
 
     acc_scr[iw] += jax.lax.dot_general(
-        a_ref[...].astype(jnp.float32), b_ref[...].astype(jnp.float32),
+        _mxu_operand(a_ref), _mxu_operand(b_ref),
         (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     @pl.when(ik == nk - 1)
@@ -129,6 +187,9 @@ def matmul_grouped(a, b, *, block_m: int = 128, block_k: int = 512,
         m, k, k2, block_m=block_m, block_k=block_k)
     w, groups = _check_working_set(working_set, nm)
     a_map, b_map, o_map = _grouped_maps(w)
+    params = _compiler_params("matmul_grouped", vmem_footprint(
+        "grouped", m, n, k, block_m=block_m, block_k=block_k,
+        working_set=w, bytes_per_el=a.dtype.itemsize))
 
     out = pl.pallas_call(
         functools.partial(_grouped_kernel, nk=nk),
@@ -140,23 +201,29 @@ def matmul_grouped(a, b, *, block_m: int = 128, block_k: int = 512,
         out_specs=pl.BlockSpec((block_m, n), o_map),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((w, block_m, n), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
     )(a, b)
     return out
 
 
-def _dispersed_kernel(a_ref, b_ref, o_ref, *, nk: int):
-    ik = pl.program_id(0)
-
-    @pl.when(ik == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    # The output tile was just refetched from HBM (a "fill"); accumulate and
-    # let the pipeline spill it back when the grid moves on.
-    o_ref[...] += jax.lax.dot_general(
-        a_ref[...].astype(o_ref.dtype), b_ref[...].astype(o_ref.dtype),
-        (((1,), (0,)), ((), ())), preferred_element_type=o_ref.dtype)
+def _dispersed_kernel(a_ref, b_ref, acc_in, acc_hbm, acc_vmem, sem, *,
+                      block_m: int):
+    # ``acc_in`` and ``acc_hbm`` are one HBM buffer (aliased input->output):
+    # every step fills its accumulator tile from HBM, adds this k block's
+    # product and spills the tile back before the grid moves on.
+    del acc_in
+    im = pl.program_id(1)
+    rows = acc_hbm.at[pl.ds(pl.multiple_of(im * block_m, block_m), block_m)]
+    fill = pltpu.make_async_copy(rows, acc_vmem, sem)
+    fill.start()
+    fill.wait()
+    acc_vmem[...] += jax.lax.dot_general(
+        _mxu_operand(a_ref), _mxu_operand(b_ref),
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    spill = pltpu.make_async_copy(acc_vmem, rows, sem)
+    spill.start()
+    spill.wait()
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -165,27 +232,36 @@ def matmul_dispersed(a, b, *, block_m: int = 128, block_k: int = 512,
                      interpret: bool = False):
     """The no-cache extreme: every accumulator revisit spills/fills HBM.
 
-    Grid (k, m) with k outermost: each output tile is written back and
-    refetched on every k step (2*M*N*nk bytes of accumulator traffic).
-    Accumulation is carried in f32 output storage.
+    Grid (k, m) with k outermost.  The f32 accumulator is an HBM buffer
+    passed in (zeroed) and aliased to the output; each step DMAs its
+    output tile in and back out (2*M*N*nk*4 bytes of accumulator traffic).
     """
     m, k = a.shape
     k2, n = b.shape
     block_m, block_k, nm, nk = _check_tiles(
         m, k, k2, block_m=block_m, block_k=block_k)
-    a_map, b_map, o_map = _dispersed_maps()
+    a_map, b_map, _ = _dispersed_maps()
+    params = _compiler_params("matmul_dispersed", vmem_footprint(
+        "dispersed", m, n, k, block_m=block_m, block_k=block_k,
+        bytes_per_el=a.dtype.itemsize))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
 
     out = pl.pallas_call(
-        functools.partial(_dispersed_kernel, nk=nk),
+        functools.partial(_dispersed_kernel, block_m=block_m),
         grid=(nk, nm),
         in_specs=[
             pl.BlockSpec((block_m, block_k), a_map),
             pl.BlockSpec((block_k, n), b_map),
+            hbm,
         ],
-        out_specs=pl.BlockSpec((block_m, n), o_map),
+        out_specs=hbm,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_m, n), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
+        input_output_aliases={2: 0},
+        compiler_params=params,
         interpret=interpret,
-    )(a, b)
+    )(a, b, jnp.zeros((m, n), jnp.float32))
     return out.astype(a.dtype)
 
 

@@ -1,13 +1,13 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-``interpret`` defaults to auto: True when no TPU is present (this container
-is CPU-only; interpret mode executes the kernel body with jnp semantics),
-False on real TPU hardware.
+The kernels compile for the TPU (``interpret=False``, the default).  On a
+CPU backend — the test suite runs under ``JAX_PLATFORMS=cpu`` — callers
+pass ``interpret=True`` explicitly, which runs the kernel body with jnp
+semantics; nothing here picks the mode from the backend.
 """
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import dispersed_gemm as _dg
@@ -15,17 +15,11 @@ from repro.kernels import flash_attention as _fa
 from repro.kernels import ref as ref
 
 
-def _auto_interpret() -> bool:
-    return jax.devices()[0].platform != "tpu"
-
-
 def flash_attention(q, k, v, *, causal: bool = False,
                     scale: float | None = None, block_q: int = 128,
-                    block_k: int = 128, interpret: bool | None = None):
+                    block_k: int = 128, interpret: bool = False):
     """FlashAttention-2 with GQA support: k/v may have fewer heads than q
     (q heads must be a multiple); they are expanded before the kernel."""
-    if interpret is None:
-        interpret = _auto_interpret()
     hq, hkv = q.shape[1], k.shape[1]
     if hkv != hq:
         if hkv == 0 or hq % hkv:
@@ -41,19 +35,15 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
 
 def matmul(a, b, *, working_set: int = 4, block_m: int = 128,
-           block_k: int = 512, interpret: bool | None = None):
+           block_k: int = 512, interpret: bool = False):
     """Grouped (compact-working-set) GEMM — the recommended schedule."""
-    if interpret is None:
-        interpret = _auto_interpret()
     return _dg.matmul_grouped(a, b, block_m=block_m, block_k=block_k,
                               working_set=working_set, interpret=interpret)
 
 
 def matmul_dispersed(a, b, *, block_m: int = 128, block_k: int = 512,
-                     interpret: bool | None = None):
+                     interpret: bool = False):
     """Fully-dispersed (HBM round-trip accumulators) GEMM — the W=0 extreme."""
-    if interpret is None:
-        interpret = _auto_interpret()
     return _dg.matmul_dispersed(a, b, block_m=block_m, block_k=block_k,
                                 interpret=interpret)
 

@@ -111,9 +111,8 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
         result["bytes_per_device"] = int(args_b + temp_b)
         result["fits_16g"] = bool(result["bytes_per_device"] < 16e9)
         del n_dev
-    cost = compiled.cost_analysis()
-    if cost:
-        c = cost[0] if isinstance(cost, (list, tuple)) else cost
+    c = compiled.cost_analysis()
+    if c:
         result["flops"] = float(c.get("flops", -1))
         result["bytes_accessed"] = float(c.get("bytes accessed", -1))
         result["transcendentals"] = float(c.get("transcendentals", 0))

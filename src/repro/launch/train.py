@@ -14,6 +14,7 @@ from __future__ import annotations
 import argparse
 
 from repro.configs import get
+from repro.launch.cache import use_compile_cache
 from repro.optim import OptConfig
 from repro.train import TrainConfig, Trainer
 
@@ -31,6 +32,7 @@ def main(argv=None) -> int:
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_launch_train")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get(args.arch)
     if args.reduced:

@@ -140,7 +140,8 @@ def attention(p, cfg, x, positions, *, causal=True, kv_input=None,
         assert window is None and kv_input is None
         out = ops.flash_attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=causal).transpose(0, 2, 1, 3)
+            v.transpose(0, 2, 1, 3), causal=causal,
+            interpret=False).transpose(0, 2, 1, 3)
     else:
         out = _sdpa(q, k, v, causal=causal and kv_input is None,
                     window=window)

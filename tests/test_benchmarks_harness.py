@@ -20,7 +20,7 @@ NAMES = ("pathfinder", "jacobi2d", "somier", "gemv", "dropout",
     ("benchmarks.fig8_power", {"max_events": 12_000, "names": NAMES}),
     ("benchmarks.pareto_frontier", {"max_events": 12_000,
                                     "names": ["dropout", "gemv"]}),
-    ("benchmarks.vmem_dispersion", {}),
+    ("benchmarks.vmem_dispersion", {"interpret": True}),
     ("benchmarks.kv_dispersion", {"steps": 150}),
     ("benchmarks.network_sweep", {"models": ("granite-8b",), "caps": (4, 8),
                                   "l1_kbytes": (4,), "max_events": 120}),
@@ -41,7 +41,15 @@ def test_suite_produces_rows(mod, kw):
         assert "name" in r
 
 
-def test_run_json_schema(tmp_path):
+@pytest.fixture
+def no_repo_compile_cache(monkeypatch, tmp_path):
+    """``run.main`` turns on the persistent compile cache; with the
+    variable set it leaves the process's setting alone, so a test never
+    writes compiled programs into the checkout."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+
+
+def test_run_json_schema(tmp_path, no_repo_compile_cache):
     """The front door's --json report: schema 7, --kernels subsetting, the
     metric-registry catalog (incl. the macro-model catalog), and per-sweep
     derived-metric metadata."""
@@ -69,7 +77,7 @@ def test_roofline_report_over_results():
     """The measured roofline needs no results/dryrun sweep: the smoke grid
     must emit >0 rows, each cross-checked against hbm_traffic_model."""
     import benchmarks.roofline as rl
-    gemm, flash, rows = rl.run_measured(smoke=True)
+    gemm, flash, rows = rl.run_measured(smoke=True, interpret=True)
     assert len(rows) > 0
     for r in rows:
         assert r["model_agree"] is True          # counted == closed form
@@ -88,7 +96,7 @@ def test_roofline_report_over_results():
     assert stats["dispatches"] > 0 and stats["compiles"] > 0
 
 
-def test_roofline_json_extra_schema_guard(tmp_path):
+def test_roofline_json_extra_schema_guard(tmp_path, no_repo_compile_cache):
     """The regression this PR fixes: the front door must never again record
     a silent 0-row roofline.  Runs the suite through run.py --json and
     pins rows/dispatches > 0 plus the measured/model row schema."""
@@ -97,7 +105,7 @@ def test_roofline_json_extra_schema_guard(tmp_path):
     from benchmarks import run as runner
     out = tmp_path / "roofline.json"
     rc = runner.main(["--json", str(out), "--max-events", "120",
-                      "roofline"])
+                      "--interpret", "roofline"])
     assert rc == 0
     rep = json.loads(out.read_text())["suites"]["roofline"]
     assert rep["rows"] > 0

@@ -29,15 +29,16 @@ def test_param_shardings_all_archs_divisible():
     """Every arch's full-size params get valid shardings on a 4x2 mesh."""
     _run("""
         import jax
+        from jax.sharding import AxisType
         from repro.configs import ARCHS
         from repro.launch import sharding as shr, specs
         from repro.models import common
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         common.set_mesh(mesh)
         for name in ARCHS:
             params, _ = specs.state_specs(name)
             sh = shr.params_shardings(params, mesh)
-            flat = jax.tree.leaves_with_path(sh) if hasattr(jax.tree, 'leaves_with_path') else None
             # shard_shape raises if any dim is not divisible
             for (path, leaf), (_, s) in zip(
                     jax.tree_util.tree_flatten_with_path(params)[0][:9999],
@@ -52,6 +53,7 @@ def test_train_cell_compiles_on_debug_mesh():
     on a 2x2 mesh with a reduced arch."""
     _run("""
         import dataclasses, jax
+        from jax.sharding import AxisType
         import repro.configs.registry as reg
         from repro.configs import get
         from repro.configs.registry import ShapeConfig
@@ -62,7 +64,8 @@ def test_train_cell_compiles_on_debug_mesh():
 
         cfg = dataclasses.replace(get("qwen3-8b").reduced(), name="dbg")
         reg.ARCHS["dbg"] = cfg
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         common.set_mesh(mesh)
         shape = ShapeConfig("t", 32, 4, "train")
         sp = specs.input_specs("dbg", shape)
@@ -81,6 +84,7 @@ def test_train_cell_compiles_on_debug_mesh():
 def test_decode_cell_compiles_on_debug_mesh():
     _run("""
         import dataclasses, jax
+        from jax.sharding import AxisType
         import repro.configs.registry as reg
         from repro.configs import get
         from repro.configs.registry import ShapeConfig
@@ -89,7 +93,8 @@ def test_decode_cell_compiles_on_debug_mesh():
 
         cfg = dataclasses.replace(get("granite-8b").reduced(), name="dbg")
         reg.ARCHS["dbg"] = cfg
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         common.set_mesh(mesh)
         shape = ShapeConfig("d", 64, 4, "decode")
         sp = specs.input_specs("dbg", shape)
@@ -109,6 +114,7 @@ def test_sharded_train_numerics_match_single_device():
     _run("""
         import dataclasses, jax, numpy as np
         import jax.numpy as jnp
+        from jax.sharding import AxisType
         from repro.configs import get
         from repro.launch import sharding as shr
         from repro.models import common
@@ -127,7 +133,8 @@ def test_sharded_train_numerics_match_single_device():
         step = make_train_step(cfg, adamw.OptConfig(), 1)
         _, _, _, m_plain = jax.jit(step)(params, opt, None, batch)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         common.set_mesh(mesh)
         psh = shr.params_shardings(params, mesh)
         osh = shr.opt_shardings(opt, psh, mesh)
@@ -146,10 +153,11 @@ def test_elastic_checkpoint_restore_across_meshes():
     _run("""
         import jax, numpy as np, tempfile
         import jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.checkpoint import Checkpointer
 
-        mesh_a = jax.make_mesh((2, 2), ("data", "model"))
+        mesh_a = jax.make_mesh((2, 2), ("data", "model"),
+                               axis_types=(AxisType.Auto,) * 2)
         sh_a = NamedSharding(mesh_a, P("data", "model"))
         x = jax.device_put(jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
                            sh_a)
@@ -157,7 +165,8 @@ def test_elastic_checkpoint_restore_across_meshes():
         ck = Checkpointer(d)
         ck.save(1, {"x": x}, blocking=True)
 
-        mesh_b = jax.make_mesh((4, 1), ("data", "model"))
+        mesh_b = jax.make_mesh((4, 1), ("data", "model"),
+                               axis_types=(AxisType.Auto,) * 2)
         sh_b = NamedSharding(mesh_b, P("data", "model"))
         step, restored = ck.restore(
             {"x": x}, shard_fn=lambda k, a: jax.device_put(a, sh_b))

@@ -64,14 +64,54 @@ def test_gemm_grouped_allclose(m, k, n, w, dtype):
                                rtol=tol, atol=tol)
 
 
-@pytest.mark.parametrize("m,k,n", [(256, 512, 128), (128, 1024, 128)])
-def test_gemm_dispersed_allclose(m, k, n):
-    a = _rand(jax.random.PRNGKey(1), (m, k), jnp.float32)
-    b = _rand(jax.random.PRNGKey(2), (k, n), jnp.float32)
+@pytest.mark.parametrize("m,k,n,dtype", [
+    (256, 512, 128, jnp.float32),
+    (128, 1024, 128, jnp.float32),
+    (512, 1024, 256, jnp.bfloat16),
+])
+def test_gemm_dispersed_allclose(m, k, n, dtype):
+    """With nm > 1 m-tiles and nk > 1 k-steps each accumulator tile is
+    revisited once per k step with the other m-tiles in between, so every
+    revisit must read back what the previous k step spilled to HBM.  The
+    interpreter reads revisited blocks back either way; only a run on the
+    chip (``chip_smoke.py`` phase (c)) tells the aliased accumulator from
+    an output-block revisit."""
+    a = _rand(jax.random.PRNGKey(1), (m, k), dtype)
+    b = _rand(jax.random.PRNGKey(2), (k, n), dtype)
     got = dispersed_gemm.matmul_dispersed(a, b, block_m=128, block_k=256,
                                           interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref.matmul_ref(a, b)),
-                               rtol=2e-4, atol=2e-4)
+    want = ref.matmul_ref(a, b)
+    tol = 6e-2 if dtype == jnp.bfloat16 else 2e-4
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=tol, atol=tol)
+
+
+def test_vmem_footprint_sizes_the_blocks():
+    """The scoped-VMEM request is the double-buffered pipeline blocks plus
+    the f32 accumulator and one f32 block product, term by term."""
+    m, n, k, bm, bk = 512, 8192, 3072, 128, 512
+    g = dispersed_gemm.vmem_footprint("grouped", m, n, k, block_m=bm,
+                                      block_k=bk, working_set=4)
+    assert g == {"a": 2 * bm * bk * 2, "b": 2 * bk * n * 2,
+                 "c": 2 * bm * n * 2, "acc": 4 * bm * n * 4,
+                 "product": bm * n * 4}
+    d = dispersed_gemm.vmem_footprint("dispersed", m, n, k, block_m=bm,
+                                      block_k=bk)
+    assert d == {"a": 2 * bm * bk * 2, "b": 2 * bk * n * 2,
+                 "acc": bm * n * 4, "product": bm * n * 4}
+    assert sum(g.values()) < dispersed_gemm.VMEM_CAP_BYTES
+
+
+def test_gemm_refuses_shapes_over_the_vmem_budget():
+    """A full-N B panel too wide for VMEM is refused by name, before the
+    chip's compiler would run out of scoped VMEM."""
+    a = jax.ShapeDtypeStruct((512, 4096), jnp.bfloat16)
+    b = jax.ShapeDtypeStruct((4096, 65536), jnp.bfloat16)
+    for fn in (dispersed_gemm.matmul_grouped,
+               dispersed_gemm.matmul_dispersed):
+        with pytest.raises(ValueError, match="VMEM footprint .* b="):
+            jax.eval_shape(fn, a, b)
 
 
 def test_gemm_grouped_bitwise_independent_of_working_set():
