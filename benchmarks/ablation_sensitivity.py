@@ -37,12 +37,10 @@ def machine_grid(l1_kb: int) -> simulator.MachineSweep:
 def run(max_events=None, fold=True, check_affine=True,
         session=None) -> list[dict]:
     ses = session or api.default_session()
-    res, dt = common.timed(
-        ses.run, api.Sweep(kernels=APPS, capacity=[8, 32],
-                           mem_latency=MEM_LATENCIES,
-                           l1_geometry=GEOMETRIES,
-                           fold=fold, max_events=max_events))
-    us_each = dt * 1e6 / (len(APPS) * len(MEM_LATENCIES) * len(L1_KBYTES))
+    res = ses.run(api.Sweep(kernels=APPS, capacity=[8, 32],
+                            mem_latency=MEM_LATENCIES,
+                            l1_geometry=GEOMETRIES,
+                            fold=fold, max_events=max_events))
     if check_affine:
         for l1_kb in L1_KBYTES:
             costmodel.check_machine_affine(
@@ -57,7 +55,6 @@ def run(max_events=None, fold=True, check_affine=True,
                 rows.append(dict(
                     name=f"{name}_mem{mem_lat}_l1_{l1_kb}k",
                     kernel=name, mem_latency=mem_lat, l1_kb=l1_kb,
-                    us_per_call=round(us_each, 1),
                     cycles=res.value("cycles", capacity=8, **pt),
                     perf_cvrf8=round(res.value("cycles", capacity=32, **pt)
                                      / res.value("cycles", capacity=8, **pt),
@@ -70,7 +67,7 @@ def run(max_events=None, fold=True, check_affine=True,
 
 def main():
     rows = run()
-    common.emit(rows, ["name", "us_per_call", "perf_cvrf8", "hit_rate"])
+    common.emit(rows, ["name", "perf_cvrf8", "hit_rate"])
     return rows
 
 

@@ -50,7 +50,7 @@ def run(names=KERNELS, cores=CORES, caps=CAPS, l1_kbytes=L1_KBYTES,
                           for kb in l1_kbytes),
         cores=tuple(cores), cluster=cluster,
         kernel_params=kernel_params, fold=fold, max_events=max_events)
-    res, dt = common.timed(ses.run, sweep)
+    res = ses.run(sweep)
     res = (res.derive("scaled_cycles").derive("sram_budget_bytes")
               .derive("cluster_area").derive("aggregate_throughput")
               .derive("contention_stall_ratio"))
@@ -58,10 +58,8 @@ def run(names=KERNELS, cores=CORES, caps=CAPS, l1_kbytes=L1_KBYTES,
         "cycles", "scaled_cycles", "contention_stalls", "l2_hits",
         "l2_misses", "core_cycles_sum", "sram_budget_bytes",
         "cluster_area", "aggregate_throughput", "contention_stall_ratio"])
-    us_each = dt * 1e6 / max(1, len(rows))
     for r in rows:
         r["name"] = r.pop("kernel")
-        r["us_per_call"] = round(us_each, 1)
     fronts = {
         name: res.pareto("sram_budget_bytes", "aggregate_throughput",
                          maximize=("aggregate_throughput",), kernel=name)
@@ -89,7 +87,7 @@ def run(names=KERNELS, cores=CORES, caps=CAPS, l1_kbytes=L1_KBYTES,
 
 def main(names=KERNELS, max_events: int | None = None) -> list[dict]:
     rows = run(names=names, max_events=max_events)
-    common.emit(rows, ["name", "us_per_call", "cores", "capacity", "l1_kb",
+    common.emit(rows, ["name", "cores", "capacity", "l1_kb",
                        "cycles", "contention_stall_ratio",
                        "sram_budget_bytes", "aggregate_throughput"])
     front = _LAST_EXTRA["iso_budget_front"]

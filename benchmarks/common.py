@@ -12,7 +12,6 @@ construct a :class:`repro.api.Sweep` and call ``Session.run`` directly.
 
 from __future__ import annotations
 
-import time
 import warnings
 
 from repro import api
@@ -59,9 +58,3 @@ def emit(rows: list[dict], header: list[str]) -> None:
     print(",".join(header))
     for r in rows:
         print(",".join(str(r.get(h, "")) for h in header))
-
-
-def timed(fn, *args, **kw):
-    t0 = time.time()
-    out = fn(*args, **kw)
-    return out, time.time() - t0

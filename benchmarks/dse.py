@@ -132,7 +132,7 @@ def run(names=KERNELS, cores=CORES, caps=CAPS, l1_kbytes=L1_KBYTES,
                           for kb in l1_kbytes),
         cores=tuple(cores), cluster=cluster,
         kernel_params=kernel_params, fold=fold, max_events=max_events)
-    res, dt = common.timed(ses.run, sweep)
+    res = ses.run(sweep)
     res = res.derive("scaled_cycles")
     # Re-price the one grid under every macro model: objective columns
     # area_<model> / energy_<model> (flop == the legacy metrics,
@@ -192,10 +192,8 @@ def run(names=KERNELS, cores=CORES, caps=CAPS, l1_kbytes=L1_KBYTES,
     rows = res.to_rows(
         ["cycles", "scaled_cycles", "fold_exact"]
         + [f"area_{m}" for m in models] + [f"energy_{m}" for m in models])
-    us_each = dt * 1e6 / max(1, len(rows))
     for r in rows:
         r["name"] = r.pop("kernel")
-        r["us_per_call"] = round(us_each, 1)
         r["fold_exact"] = bool(r["fold_exact"])
     plan = res.meta["plan"]
     _LAST_EXTRA.clear()
@@ -219,7 +217,7 @@ def run(names=KERNELS, cores=CORES, caps=CAPS, l1_kbytes=L1_KBYTES,
 
 def main(names=KERNELS, max_events: int | None = None) -> list[dict]:
     rows = run(names=names, max_events=max_events)
-    common.emit(rows, ["name", "us_per_call", "cores", "capacity", "l1_kb",
+    common.emit(rows, ["name", "cores", "capacity", "l1_kb",
                        "cycles", "area_flop", "area_sram6t",
                        "energy_flop", "energy_sram6t"])
     fronts = _LAST_EXTRA["fronts"]

@@ -29,7 +29,7 @@ REF_WORDS, REF_BITS = 512, 256
 
 def run() -> list[dict]:
     head = metrics.area_headline(n_full=32, n_cvrf=8)
-    rows = [dict(name=name, us_per_call=0.0, value=round(value, 2),
+    rows = [dict(name=name, value=round(value, 2),
                  paper=PAPER[name])
             for name, value in head.items()]
     # the macro-model calibration rows, through the silicon registry
@@ -37,18 +37,18 @@ def run() -> list[dict]:
     flop_area = cat["flop"]["area_au"]
     for name, rec in cat.items():
         rows.append(dict(
-            name=f"l1_16kb_macro_area_au[{name}]", us_per_call=0.0,
+            name=f"l1_16kb_macro_area_au[{name}]",
             value=round(rec["area_au"], 1),
             vs_flop=round(rec["area_au"] / flop_area, 3)))
         rows.append(dict(
-            name=f"l1_16kb_access_energy[{name}]", us_per_call=0.0,
+            name=f"l1_16kb_access_energy[{name}]",
             value=round(rec["access_energy"], 2)))
     return rows
 
 
 def main():
     rows = run()
-    common.emit(rows, ["name", "us_per_call", "value", "paper", "vs_flop"])
+    common.emit(rows, ["name", "value", "paper", "vs_flop"])
     return rows
 
 

@@ -19,17 +19,15 @@ CAPS = list(range(3, 17))
 def run(names=None, max_events=None, fold=True, session=None) -> list[dict]:
     names = list(names or rvv.BENCHMARKS)
     ses = session or api.default_session()
-    res, dt = common.timed(
-        ses.run, api.Sweep(kernels=names, capacity=CAPS + [32],
-                           fold=fold, max_events=max_events))
-    us_each = dt * 1e6 / len(names)
+    res = ses.run(api.Sweep(kernels=names, capacity=CAPS + [32],
+                            fold=fold, max_events=max_events))
     r = res.derive("speedup", baseline=dict(capacity=32))
     rows = []
     for name in names:
         for cap in CAPS:
             pt = dict(kernel=name, capacity=cap)
             rows.append(dict(
-                name=name, us_per_call=round(us_each, 1), capacity=cap,
+                name=name, capacity=cap,
                 norm_perf=round(r.value("speedup", **pt), 4),
                 hit_rate=round(r.value("hit_rate", **pt), 4),
                 spills=r.value("spills", **pt),
@@ -41,7 +39,7 @@ def run(names=None, max_events=None, fold=True, session=None) -> list[dict]:
 
 def main(names=None, max_events=None):
     rows = run(names=names, max_events=max_events)
-    common.emit(rows, ["name", "us_per_call", "capacity", "norm_perf",
+    common.emit(rows, ["name", "capacity", "norm_perf",
                        "hit_rate", "spills", "fills", "fold_exact"])
     return rows
 

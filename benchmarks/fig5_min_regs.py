@@ -20,10 +20,8 @@ def run(max_events=None, fold=True, target=0.95, names=None,
         session=None) -> list[dict]:
     names = list(names or rvv.BENCHMARKS)
     ses = session or api.default_session()
-    res, dt = common.timed(
-        ses.run, api.Sweep(kernels=names, capacity=CAPS + [32],
-                           fold=fold, max_events=max_events))
-    us_each = dt * 1e6 / len(names)
+    res = ses.run(api.Sweep(kernels=names, capacity=CAPS + [32],
+                            fold=fold, max_events=max_events))
     rows = []
     for name in names:
         hit = {c: res.value("hit_rate", kernel=name, capacity=c)
@@ -31,7 +29,7 @@ def run(max_events=None, fold=True, target=0.95, names=None,
         ok = [c for c in CAPS if hit[c] > target]
         min_regs = min(ok) if ok else max(CAPS) + 1
         rows.append(dict(
-            name=name, us_per_call=round(us_each, 1),
+            name=name,
             min_regs=min_regs, paper_min=PAPER_MIN.get(name, ""),
             active_regs=len(ses.built(name).program.active_vregs()),
             hit_at_min=round(hit.get(min_regs, 0.0), 4),
@@ -41,7 +39,7 @@ def run(max_events=None, fold=True, target=0.95, names=None,
 
 def main(names=None, max_events=None):
     rows = run(names=names, max_events=max_events)
-    common.emit(rows, ["name", "us_per_call", "min_regs", "paper_min",
+    common.emit(rows, ["name", "min_regs", "paper_min",
                        "active_regs", "hit_at_min"])
     return rows
 

@@ -23,15 +23,13 @@ FULL = dict(capacity=32)
 def run(max_events=None, fold=True, names=None, session=None) -> list[dict]:
     names = list(names or rvv.BENCHMARKS)
     ses = session or api.default_session()
-    res, dt = common.timed(
-        ses.run, api.Sweep(kernels=names, capacity=[8, 32],
-                           fold=fold, max_events=max_events))
-    us_each = dt * 1e6 / len(names)
+    res = ses.run(api.Sweep(kernels=names, capacity=[8, 32],
+                            fold=fold, max_events=max_events))
     r = (res.derive("speedup", baseline=FULL)
             .derive("narrow_vrf_speedup")
             .derive("equal_area_advantage", baseline=FULL))
     return [dict(
-        name=name, us_per_call=round(us_each, 1),
+        name=name,
         dispersion_8x256=round(r.value("speedup", kernel=name,
                                        capacity=8), 3),
         narrow_32x64=round(r.value("narrow_vrf_speedup", kernel=name,
@@ -43,7 +41,7 @@ def run(max_events=None, fold=True, names=None, session=None) -> list[dict]:
 
 def main(names=None, max_events=None):
     rows = run(names=names, max_events=max_events)
-    common.emit(rows, ["name", "us_per_call", "dispersion_8x256",
+    common.emit(rows, ["name", "dispersion_8x256",
                        "narrow_32x64", "advantage"])
     return rows
 

@@ -17,15 +17,13 @@ from repro import api, rvv
 def run(max_events=None, fold=True, names=None, session=None) -> list[dict]:
     names = list(names or rvv.BENCHMARKS)
     ses = session or api.default_session()
-    res, dt = common.timed(
-        ses.run, api.Sweep(kernels=names, capacity=[8, 32],
-                           fold=fold, max_events=max_events))
-    us_each = dt * 1e6 / len(names)
+    res = ses.run(api.Sweep(kernels=names, capacity=[8, 32],
+                            fold=fold, max_events=max_events))
     r = (res.derive("application_power")
             .derive("savings_pct", of="application_power",
                     baseline=dict(capacity=32), out="power_saving_pct"))
     rows = [dict(
-        name=name, us_per_call=round(us_each, 1),
+        name=name,
         power_full=round(r.value("application_power", kernel=name,
                                  capacity=32), 2),
         power_cvrf8=round(r.value("application_power", kernel=name,
@@ -34,7 +32,7 @@ def run(max_events=None, fold=True, names=None, session=None) -> list[dict]:
                                  capacity=8), 1),
     ) for name in names]
     avg = float(np.mean(r.array("power_saving_pct", capacity=8)))
-    rows.append(dict(name="AVERAGE", us_per_call=0.0,
+    rows.append(dict(name="AVERAGE",
                      power_full="", power_cvrf8="",
                      saving_pct=round(avg, 1), paper_saving=10.0))
     return rows
@@ -42,7 +40,7 @@ def run(max_events=None, fold=True, names=None, session=None) -> list[dict]:
 
 def main(names=None, max_events=None):
     rows = run(names=names, max_events=max_events)
-    common.emit(rows, ["name", "us_per_call", "power_full", "power_cvrf8",
+    common.emit(rows, ["name", "power_full", "power_cvrf8",
                        "saving_pct", "paper_saving"])
     return rows
 

@@ -35,15 +35,14 @@ def run(models=MODELS, caps=CAPS, l1_kbytes=L1_KBYTES, max_events=None,
         l1_geometry=tuple(api.L1Geometry.from_kbytes(kb)
                           for kb in l1_kbytes),
         fold=fold, max_events=max_events)
-    res, dt = common.timed(ses.run, sweep)
+    res = ses.run(sweep)
     res = res.derive("scaled_cycles").derive("energy")
     lowered = list(getattr(sweep, "_lowered"))
-    us_each = dt * 1e6 / max(1, len(sweep.kernels))
     rows = []
     for r in bridge.network_report(res, lowered,
                                    metrics=("scaled_cycles", "energy")):
         rows.append(dict(
-            name=r["model"], us_per_call=round(us_each, 1),
+            name=r["model"],
             capacity=r["capacity"], l1_kb=r["l1_kb"],
             footprint_bytes=r["footprint_bytes"], kernels=r["kernels"],
             instances=r["instances"],
@@ -66,7 +65,7 @@ def run(models=MODELS, caps=CAPS, l1_kbytes=L1_KBYTES, max_events=None,
 
 def main(max_events: int | None = None) -> list[dict]:
     rows = run(max_events=max_events)
-    common.emit(rows, ["name", "us_per_call", "capacity", "l1_kb",
+    common.emit(rows, ["name", "capacity", "l1_kb",
                        "footprint_bytes", "kernels", "instances",
                        "cycles_total", "energy_total"])
     return rows

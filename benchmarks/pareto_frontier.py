@@ -26,11 +26,9 @@ def run(max_events=None, fold=True, names=None, session=None,
         caps=CAPS, geometries=GEOMETRIES) -> list[dict]:
     names = list(names or rvv.BENCHMARKS)
     ses = session or api.default_session()
-    res, dt = common.timed(
-        ses.run, api.Sweep(kernels=names, capacity=list(caps),
-                           l1_geometry=list(geometries),
-                           fold=fold, max_events=max_events))
-    us_each = dt * 1e6 / len(names)
+    res = ses.run(api.Sweep(kernels=names, capacity=list(caps),
+                            l1_geometry=list(geometries),
+                            fold=fold, max_events=max_events))
     r = res.derive("area_with_l1").derive("scaled_cycles")
     rows = []
     for name in names:
@@ -38,7 +36,7 @@ def run(max_events=None, fold=True, names=None, session=None,
         n_points = len(caps) * len(geometries)
         for f in front:
             rows.append(dict(
-                name=name, us_per_call=round(us_each, 1),
+                name=name,
                 capacity=f["capacity"], l1_kb=f["l1_kb"],
                 area_with_l1=round(f["area_with_l1"], 0),
                 cycles=int(f["scaled_cycles"]),
@@ -49,7 +47,7 @@ def run(max_events=None, fold=True, names=None, session=None,
 
 def main(names=None, max_events=None):
     rows = run(names=names, max_events=max_events)
-    common.emit(rows, ["name", "us_per_call", "capacity", "l1_kb",
+    common.emit(rows, ["name", "capacity", "l1_kb",
                        "area_with_l1", "cycles", "front_size",
                        "grid_points"])
     return rows

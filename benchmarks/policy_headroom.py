@@ -39,18 +39,15 @@ def config_points() -> list[api.ConfigPoint]:
 
 def run(max_events=None, fold=True, session=None) -> list[dict]:
     ses = session or api.default_session()
-    res, dt = common.timed(
-        ses.run, api.Sweep(kernels=APPS, config_points=config_points(),
-                           fold=fold, max_events=max_events))
-    us_each = dt * 1e6 / len(APPS)
+    res = ses.run(api.Sweep(kernels=APPS, config_points=config_points(),
+                            fold=fold, max_events=max_events))
     r = (res.derive("delta", of="hit_rate", baseline=FIFO_BASE,
                     out="hit_rate_gain")
             .derive("speedup", baseline=FIFO_BASE))
     rows = []
     for name in APPS:
         for cap in CAPS:
-            row = dict(name=name, capacity=cap,
-                       us_per_call=round(us_each, 1))
+            row = dict(name=name, capacity=cap)
             for pol in POLS:
                 row[policies.POLICY_NAMES[pol]] = round(
                     r.value("hit_rate", kernel=name, capacity=cap,
@@ -72,7 +69,7 @@ def run(max_events=None, fold=True, session=None) -> list[dict]:
 
 def main(max_events=None):
     rows = run(max_events=max_events)
-    common.emit(rows, ["name", "us_per_call", "capacity", "fifo", "lru",
+    common.emit(rows, ["name", "capacity", "fifo", "lru",
                        "lfu", "opt", "opt_headroom", "fifo_cycles",
                        "fifo_no_fetch_cycles", "no_fetch_speedup"])
     return rows

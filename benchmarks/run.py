@@ -1,5 +1,5 @@
 """Benchmark harness: one function per paper table/figure (+ beyond-paper
-studies).  Prints ``name,us_per_call,derived...`` CSV blocks per benchmark.
+studies).  Prints ``name,derived...`` CSV blocks per benchmark.
 
   python -m benchmarks.run                       # everything
   python -m benchmarks.run table3 fig4           # subset
@@ -15,7 +15,7 @@ same way, and ``--interpret`` runs the Pallas suites (roofline,
 vmem_dispersion) in the Pallas interpreter — needed on the CPU backend,
 where the kernels cannot compile.
 
-``--json PATH`` writes a versioned report (``schema: 6``): per-suite
+``--json PATH`` writes a versioned report (``schema: 7``): per-suite
 wall-clock, XLA compile AND dispatch counts (the fused engine compiles once
 per (program-shape bucket, L1 geometry) — machine-latency grids are traced,
 so they add rows, not compiles), the sweep-axis metadata of every

@@ -19,10 +19,8 @@ from repro.core import isa
 def run(max_events=None, fold=True, names=None, session=None) -> list[dict]:
     names = list(names or rvv.BENCHMARKS)
     ses = session or api.default_session()
-    res, dt = common.timed(
-        ses.run, api.Sweep(kernels=names, capacity=[isa.NUM_ARCH_VREGS],
-                           fold=fold, max_events=max_events))
-    us_each = dt * 1e6 / len(names)
+    res = ses.run(api.Sweep(kernels=names, capacity=[isa.NUM_ARCH_VREGS],
+                            fold=fold, max_events=max_events))
     r = res.derive("scalar_speedup")    # pulls scalar_cycles+scaled_cycles
     rows = []
     for name in names:
@@ -31,7 +29,7 @@ def run(max_events=None, fold=True, names=None, session=None) -> list[dict]:
                                                 util=""))
         active = len(ses.built(name).program.active_vregs())
         rows.append(dict(
-            name=name, us_per_call=round(us_each, 1),
+            name=name,
             speedup=round(r.value("scalar_speedup", kernel=name), 2),
             paper_speedup=paper["speedup"],
             active_regs=active, paper_active=paper["active_regs"],
@@ -45,7 +43,7 @@ def run(max_events=None, fold=True, names=None, session=None) -> list[dict]:
 
 def main(names=None, max_events=None):
     rows = run(names=names, max_events=max_events)
-    common.emit(rows, ["name", "us_per_call", "speedup", "paper_speedup",
+    common.emit(rows, ["name", "speedup", "paper_speedup",
                        "active_regs", "paper_active", "vrf_util",
                        "paper_util", "vec_cycles", "scalar_cycles"])
     return rows

@@ -47,6 +47,7 @@ import math
 
 import numpy as np
 
+from repro import obs
 from repro.core import folding, policies, simulator
 from repro.core.simulator import (DEFAULT_MACHINE, MachineSweep,
                                   SweepConfig)
@@ -847,17 +848,21 @@ class Session:
         failed at any grid point and whose full trace is affordable."""
         if "fold_exact" not in out:
             return
-        for pi, name in enumerate(names):
-            if out["fold_exact"][pi].all():
-                continue
-            rows = self.built(name, params).program.num_instructions
-            if rows > self.refine_max_rows:
-                continue
-            sub = self._simulate(
-                [self.prepared(name, fold=False, machine=machine,
-                               params=params)], config, machine)
-            for k in out:
-                out[k][pi] = sub[k][0] if k != "fold_exact" else True
+        with obs.span("session.refine") as sp:
+            redone = 0
+            for pi, name in enumerate(names):
+                if out["fold_exact"][pi].all():
+                    continue
+                rows = self.built(name, params).program.num_instructions
+                if rows > self.refine_max_rows:
+                    continue
+                sub = self._simulate(
+                    [self.prepared(name, fold=False, machine=machine,
+                                   params=params)], config, machine)
+                for k in out:
+                    out[k][pi] = sub[k][0] if k != "fold_exact" else True
+                redone += 1
+            sp.set(programs=redone)
 
     def _refine_cluster(self, names, out, config, machine, sweep) -> None:
         """Cluster analogue of :meth:`_refine`: re-simulate, unfolded and
@@ -866,23 +871,27 @@ class Session:
         that holds single-core, so certificates are per (kernel, cores))."""
         if "fold_exact" not in out:
             return
-        for pi, name in enumerate(names):
-            if out["fold_exact"][pi].all():
-                continue
-            rows = self.built(
-                name, sweep.kernel_params).program.num_instructions
-            if rows > self.refine_max_rows:
-                continue
-            prep = self.prepared(name, fold=False, machine=machine,
-                                 params=sweep.kernel_params)
-            for ki, n in enumerate(sweep.cores):
-                if out["fold_exact"][pi, ki].all():
+        with obs.span("session.refine") as sp:
+            redone = 0
+            for pi, name in enumerate(names):
+                if out["fold_exact"][pi].all():
                     continue
-                sub = self._simulate_cluster(
-                    [prep], config, machine, sweep.cluster_config(n))
-                for k in out:
-                    out[k][pi, ki] = sub[k][0] if k != "fold_exact" \
-                        else True
+                rows = self.built(
+                    name, sweep.kernel_params).program.num_instructions
+                if rows > self.refine_max_rows:
+                    continue
+                prep = self.prepared(name, fold=False, machine=machine,
+                                     params=sweep.kernel_params)
+                for ki, n in enumerate(sweep.cores):
+                    if out["fold_exact"][pi, ki].all():
+                        continue
+                    sub = self._simulate_cluster(
+                        [prep], config, machine, sweep.cluster_config(n))
+                    for k in out:
+                        out[k][pi, ki] = sub[k][0] if k != "fold_exact" \
+                            else True
+                redone += 1
+            sp.set(programs=redone)
 
     # -- execution --------------------------------------------------------
 
@@ -929,62 +938,107 @@ class Session:
         fold = self.fold if sweep.fold is None else sweep.fold
         if sweep.max_events is not None:
             fold = False
+        c0, d0 = self._compiles, self._dispatches
+        axes = sweep.axes()
+        points = int(np.prod([len(a) for a in axes]))
+        with obs.span("session.run", kernels=len(sweep.kernels),
+                      points=points, geometries=len(sweep.l1_geometry)):
+            plan: list[dict] = []
+            per_geo = [self._run_geometry(sweep, geo, fold, plan)
+                       for geo in sweep.l1_geometry]
+            with obs.span("session.assemble"):
+                data = self._assemble(sweep, per_geo)
+                meta = dict(
+                    plan=plan,
+                    compiles=self._compiles - c0,
+                    dispatches=self._dispatches - d0,
+                    points=points,
+                    axes={a.name: [str(v) if a.name in ("l1_geometry",
+                                                        "config")
+                                   else v for v in a.values] for a in axes},
+                    kernel_params=(sweep.kernel_params
+                                   if isinstance(sweep.kernel_params, str)
+                                   else dict(sweep.kernel_params)),
+                    fold=fold,
+                )
+                if sweep.is_cluster:
+                    cl0 = sweep.cluster_config(1)
+                    meta["cluster"] = dict(
+                        cores=list(sweep.cores), l2_sets=cl0.l2_sets,
+                        l2_ways=cl0.l2_ways, mem_channels=cl0.mem_channels,
+                        l2_hit_cycles=cl0.l2_hit_cycles,
+                        l2_bytes=cl0.l2_bytes)
+                lowered = getattr(sweep, "_lowered", ())
+                if lowered:
+                    meta["networks"] = [net.summary() for net in lowered]
+        self.history.append(meta)
+        return SweepResult(axes, data, meta)
+
+    def _run_geometry(self, sweep: Sweep, geo: L1Geometry, fold: bool,
+                      plan: list) -> dict[str, np.ndarray]:
+        """One geometry of :meth:`run`: prepare, dispatch each bucket
+        group (appending its entries to ``plan``), refine.  Returns the
+        (P, C, M) — (P, K, C, M) for a cluster sweep — counter arrays."""
         names = list(sweep.kernels)
         config = sweep.config()
-        c0, d0 = self._compiles, self._dispatches
         cluster_mode = sweep.is_cluster
-        plan = []
-        per_geo = []
-        for geo in sweep.l1_geometry:
-            machines = sweep.machine_sweep(geo)
+        machines = sweep.machine_sweep(geo)
+        with obs.span("session.prepare") as sp:
+            n0 = len(self._prepared)
             preps = {n: self.prepared(n, fold=fold,
                                       max_events=sweep.max_events,
                                       machine=machines,
                                       params=sweep.kernel_params)
                      for n in names}
-            groups: dict[int, list[str]] = {}
-            for n in names:
-                bucket = simulator._bucket(preps[n].num_rows)
-                groups.setdefault(bucket, []).append(n)
-            parts: dict[str, dict[str, np.ndarray]] = {}
-            for bucket in sorted(groups):
-                group = groups[bucket]
-                group_preps = [preps[n] for n in group]
-                if cluster_mode:
-                    subs = []
-                    for ncores in sweep.cores:
-                        subs.append(self._simulate_cluster(
-                            group_preps, config, machines,
-                            sweep.cluster_config(ncores)))
-                        plan.append(dict(
-                            l1_geometry=str(geo), bucket=bucket,
-                            cores=ncores, kernels=list(group),
-                            fused=bool(self.batch_programs)))
-                    for gi, n in enumerate(group):
-                        parts[n] = {k: np.stack([s[k][gi] for s in subs])
-                                    for k in subs[0]}        # (K, C, M)
-                else:
-                    sub = self._simulate(group_preps, config, machines)
-                    plan.append(dict(l1_geometry=str(geo), bucket=bucket,
-                                     kernels=list(group),
-                                     fused=bool(self.batch_programs)))
-                    for gi, n in enumerate(group):
-                        parts[n] = {k: v[gi] for k, v in sub.items()}
-            shape_cm = parts[names[0]]["cycles"].shape  # (C, M) / (K, C, M)
-            for n in names:                  # normalise across buckets
-                parts[n].setdefault(
-                    "fold_exact", np.ones(shape_cm, bool))
-            geo_out = {k: np.stack([parts[n][k] for n in names])
-                       for k in parts[names[0]]}
-            if fold and self.refine:
-                if cluster_mode:
-                    self._refine_cluster(names, geo_out, config, machines,
-                                         sweep)
-                else:
-                    self._refine(names, geo_out, config, machines,
-                                 sweep.kernel_params)
-            per_geo.append(geo_out)
-        axes = sweep.axes()
+            misses = len(self._prepared) - n0
+            sp.set(hits=len(names) - misses, misses=misses)
+        groups: dict[int, list[str]] = {}
+        for n in names:
+            bucket = simulator._bucket(preps[n].num_rows)
+            groups.setdefault(bucket, []).append(n)
+        parts: dict[str, dict[str, np.ndarray]] = {}
+        for bucket in sorted(groups):
+            group = groups[bucket]
+            group_preps = [preps[n] for n in group]
+            if cluster_mode:
+                subs = []
+                for ncores in sweep.cores:
+                    subs.append(self._simulate_cluster(
+                        group_preps, config, machines,
+                        sweep.cluster_config(ncores)))
+                    plan.append(dict(
+                        l1_geometry=str(geo), bucket=bucket,
+                        cores=ncores, kernels=list(group),
+                        fused=bool(self.batch_programs)))
+                for gi, n in enumerate(group):
+                    parts[n] = {k: np.stack([s[k][gi] for s in subs])
+                                for k in subs[0]}        # (K, C, M)
+            else:
+                sub = self._simulate(group_preps, config, machines)
+                plan.append(dict(l1_geometry=str(geo), bucket=bucket,
+                                 kernels=list(group),
+                                 fused=bool(self.batch_programs)))
+                for gi, n in enumerate(group):
+                    parts[n] = {k: v[gi] for k, v in sub.items()}
+        shape_cm = parts[names[0]]["cycles"].shape  # (C, M) / (K, C, M)
+        for n in names:                  # normalise across buckets
+            parts[n].setdefault(
+                "fold_exact", np.ones(shape_cm, bool))
+        geo_out = {k: np.stack([parts[n][k] for n in names])
+                   for k in parts[names[0]]}
+        if fold and self.refine:
+            if cluster_mode:
+                self._refine_cluster(names, geo_out, config, machines,
+                                     sweep)
+            else:
+                self._refine(names, geo_out, config, machines,
+                             sweep.kernel_params)
+        return geo_out
+
+    @staticmethod
+    def _assemble(sweep: Sweep, per_geo: list) -> dict[str, np.ndarray]:
+        """Stack the per-geometry grids into the result's canonical axis
+        order: kernel, config axes, geometry (, cores), machine axes."""
         if sweep.config_points is not None:
             cshape = (len(sweep.config_points),)
         else:
@@ -994,7 +1048,7 @@ class Session:
                   len(sweep.uop_hit_cycles))
         data = {}
         for k in per_geo[0]:
-            if cluster_mode:
+            if sweep.is_cluster:
                 # (G, P, K, C, M) -> geometry and cores move to their
                 # canonical slots after the config axes.
                 stacked = np.stack([g[k] for g in per_geo])
@@ -1010,29 +1064,7 @@ class Session:
                 # geometry moves to its canonical slot: after the config
                 # axes.
                 data[k] = np.moveaxis(stacked, 0, 1 + len(cshape))
-        meta = dict(
-            plan=plan,
-            compiles=self._compiles - c0,
-            dispatches=self._dispatches - d0,
-            points=int(np.prod([len(a) for a in axes])),
-            axes={a.name: [str(v) if a.name in ("l1_geometry", "config")
-                           else v for v in a.values] for a in axes},
-            kernel_params=(sweep.kernel_params
-                           if isinstance(sweep.kernel_params, str)
-                           else dict(sweep.kernel_params)),
-            fold=fold,
-        )
-        if cluster_mode:
-            cl0 = sweep.cluster_config(1)
-            meta["cluster"] = dict(
-                cores=list(sweep.cores), l2_sets=cl0.l2_sets,
-                l2_ways=cl0.l2_ways, mem_channels=cl0.mem_channels,
-                l2_hit_cycles=cl0.l2_hit_cycles, l2_bytes=cl0.l2_bytes)
-        lowered = getattr(sweep, "_lowered", ())
-        if lowered:
-            meta["networks"] = [net.summary() for net in lowered]
-        self.history.append(meta)
-        return SweepResult(axes, data, meta)
+        return data
 
 
 # ---------------------------------------------------------------------------

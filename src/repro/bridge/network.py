@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 
+from repro import obs
 from repro.bridge import lower, shapes
 from repro.rvv import common as rvv_common
 
@@ -75,19 +76,23 @@ def lower_network(model: str) -> LoweredNetwork:
     """Lower registry model ``model``; idempotent (re-lowering a model, or
     lowering two models sharing a layer shape, reuses registered kernels).
     """
-    groups: dict[tuple, list] = {}
-    for op in shapes.model_ops(model):
-        groups.setdefault(op.signature, []).append(op)
-    units = []
-    for sig, ops in sorted(groups.items(), key=lambda kv: repr(kv[0])):
-        name, kwargs, macro = lower.tile_for(ops[0])
-        _register(name, ops[0].kind, kwargs, ops[0])
-        units.append(NetworkUnit(
-            kernel=name, kind=ops[0].kind,
-            labels=tuple(o.label for o in ops), shape=tuple(sig[1:]),
-            count=sum(o.count for o in ops), macro_factor=macro,
-            params=dict(kwargs)))
-    return LoweredNetwork(model=model, units=tuple(units))
+    with obs.span("bridge.lower", model=model) as sp:
+        layer_ops = shapes.model_ops(model)
+        groups: dict[tuple, list] = {}
+        for op in layer_ops:
+            groups.setdefault(op.signature, []).append(op)
+        units = []
+        for sig, ops in sorted(groups.items(), key=lambda kv: repr(kv[0])):
+            name, kwargs, macro = lower.tile_for(ops[0])
+            _register(name, ops[0].kind, kwargs, ops[0])
+            units.append(NetworkUnit(
+                kernel=name, kind=ops[0].kind,
+                labels=tuple(o.label for o in ops), shape=tuple(sig[1:]),
+                count=sum(o.count for o in ops), macro_factor=macro,
+                params=dict(kwargs)))
+        net = LoweredNetwork(model=model, units=tuple(units))
+        sp.set(kernels=len(net.kernels), ops=len(layer_ops))
+    return net
 
 
 def network_report(result, lowered, metrics=("scaled_cycles",),

@@ -205,20 +205,25 @@ def simulate_cluster_grid(preps: list, sweep: SweepConfig,
     if batch_programs:
         arrays, spill0s, slots_used = simulator._stack(preps)
         track_ab = any(p.num_folds for p in preps)
-        ctr, ctrA, ctrB = _dispatch_cluster_grid(
-            cluster, machines, slots_used, track_ab, arrays, spill0s,
-            strides, cfg, mach)
-        ctr, ctrA, ctrB = (np.asarray(x) for x in (ctr, ctrA, ctrB))
-    else:
-        outs = []
-        for prep, stride in zip(preps, strides):
-            arrays, spill0s, slots_used = simulator._stack([prep])
-            outs.append(_dispatch_cluster_grid(
-                cluster, machines, slots_used, prep.num_folds > 0, arrays,
-                spill0s, stride[None], cfg, mach))
-        ctr = np.concatenate([np.asarray(o[0]) for o in outs])
-        ctrA = np.concatenate([np.asarray(o[1]) for o in outs])
-        ctrB = np.concatenate([np.asarray(o[2]) for o in outs])
+    c0 = simulator._COMPILES
+    with simulator.dispatch_span("cluster", preps, sweep, machines,
+                                 batch_programs) as sp:
+        if batch_programs:
+            ctr, ctrA, ctrB = _dispatch_cluster_grid(
+                cluster, machines, slots_used, track_ab, arrays, spill0s,
+                strides, cfg, mach)
+            ctr, ctrA, ctrB = (np.asarray(x) for x in (ctr, ctrA, ctrB))
+        else:
+            outs = []
+            for prep, stride in zip(preps, strides):
+                arrays, spill0s, slots_used = simulator._stack([prep])
+                outs.append(_dispatch_cluster_grid(
+                    cluster, machines, slots_used, prep.num_folds > 0,
+                    arrays, spill0s, stride[None], cfg, mach))
+            ctr = np.concatenate([np.asarray(o[0]) for o in outs])
+            ctrA = np.concatenate([np.asarray(o[1]) for o in outs])
+            ctrB = np.concatenate([np.asarray(o[2]) for o in outs])
+        sp.set(compiled=simulator._COMPILES != c0)
     if squeeze_m:                                   # (P, C, M, N, 15)
         ctr, ctrA, ctrB = ctr[:, :, 0], ctrA[:, :, 0], ctrB[:, :, 0]
     per_core = {k: ctr[..., i] for i, k in enumerate(CLUSTER_COUNTER_NAMES)}

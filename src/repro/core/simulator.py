@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import events as ev_mod
 from repro.core import folding, isa, policies
 from repro.core.events import NO_NEXT_USE, EventStream
@@ -523,42 +524,45 @@ def _bucket(t: int) -> int:
 
 def _stack(preps: list[PreparedTrace], pad_to: int | None = None):
     t_pad = pad_to or _bucket(max(p.num_rows for p in preps))
+    with obs.span("engine.stack", bucket=t_pad,
+                  programs=len(preps)) as sp:
+        def pad(get, fill, dtype=None):
+            outs = []
+            for pr in preps:
+                a = get(pr)
+                if a.ndim == 1:
+                    full = np.full(t_pad, fill, a.dtype if dtype is None
+                                   else dtype)
+                else:
+                    full = np.full((t_pad, a.shape[1]), fill,
+                                   a.dtype if dtype is None else dtype)
+                full[: len(a)] = a
+                outs.append(full)
+            return np.stack(outs)
 
-    def pad(get, fill, dtype=None):
-        outs = []
-        for pr in preps:
-            a = get(pr)
-            if a.ndim == 1:
-                full = np.full(t_pad, fill, a.dtype if dtype is None
-                               else dtype)
-            else:
-                full = np.full((t_pad, a.shape[1]), fill,
-                               a.dtype if dtype is None else dtype)
-            full[: len(a)] = a
-            outs.append(full)
-        return np.stack(outs)
-
-    arrays = (
-        pad(lambda p: p.ev.reg_valid, False),
-        pad(lambda p: p.ev.reg, 0),
-        pad(lambda p: p.ev.vd_writes, False),
-        pad(lambda p: p.ev.vd_reads, False),
-        pad(lambda p: p.ev.vd_no_fetch, False),
-        pad(lambda p: p.ev.lock_vs1, -1),
-        pad(lambda p: p.ev.lock_vs2, -1),
-        pad(lambda p: p.ev.mem_valid, False),
-        pad(lambda p: p.ev.mem_line, -1),
-        pad(lambda p: p.ev.mem_write, False),
-        pad(lambda p: p.ev.cost, 0),
-        pad(lambda p: p.ev.next_use, NO_NEXT_USE),
-        pad(lambda p: p.weight, 0),
-        pad(lambda p: p.wa, 0),
-        pad(lambda p: p.wb, 0),
-    )
-    spill0s = np.asarray([p.spill_line0 for p in preps], np.int32)
-    slots_used = tuple(
-        bool(arrays[0][:, :, s].any()) for s in range(3)
-    ) + tuple(bool(arrays[7][:, :, m].any()) for m in range(2))
+        arrays = (
+            pad(lambda p: p.ev.reg_valid, False),
+            pad(lambda p: p.ev.reg, 0),
+            pad(lambda p: p.ev.vd_writes, False),
+            pad(lambda p: p.ev.vd_reads, False),
+            pad(lambda p: p.ev.vd_no_fetch, False),
+            pad(lambda p: p.ev.lock_vs1, -1),
+            pad(lambda p: p.ev.lock_vs2, -1),
+            pad(lambda p: p.ev.mem_valid, False),
+            pad(lambda p: p.ev.mem_line, -1),
+            pad(lambda p: p.ev.mem_write, False),
+            pad(lambda p: p.ev.cost, 0),
+            pad(lambda p: p.ev.next_use, NO_NEXT_USE),
+            pad(lambda p: p.weight, 0),
+            pad(lambda p: p.wa, 0),
+            pad(lambda p: p.wb, 0),
+        )
+        spill0s = np.asarray([p.spill_line0 for p in preps], np.int32)
+        slots_used = tuple(
+            bool(arrays[0][:, :, s].any()) for s in range(3)
+        ) + tuple(bool(arrays[7][:, :, m].any()) for m in range(2))
+        if sp:
+            sp.set(bytes=sum(a.nbytes for a in arrays))
     return arrays, spill0s, slots_used
 
 
@@ -575,6 +579,25 @@ def _dispatch_grid(machine: MachineSweep, slots_used, track_ab, arrays,
         return _run_grid(machine.l1_sets, machine.l1_ways, slots_used,
                          track_ab, tuple(jnp.asarray(a) for a in arrays),
                          jnp.asarray(spill0s), cfg, mach)
+
+
+def dispatch_span(engine: str, preps: list, sweep: SweepConfig,
+                  machines: MachineSweep, fused: bool):
+    """The ``engine.dispatch`` span of one engine call, from the call to
+    its counters on the host (every call blocks on them, so the span
+    covers the device's work).  ``steps`` counts bucket rows scanned: once
+    for a fused dispatch, whose lanes advance together, once per program
+    on the per-program path, whose stacks then nest inside the span."""
+    sp = obs.span("engine.dispatch", engine=engine)
+    if sp:
+        buckets = [_bucket(p.num_rows) for p in preps]
+        sp.set(steps=max(buckets) if fused else sum(buckets),
+               programs=len(preps),
+               lanes=(len(preps) * len(sweep.capacity)
+                      * len(machines.mem_latency)),
+               rows=sum(p.num_rows for p in preps),
+               dispatches=1 if fused else len(preps))
+    return sp
 
 
 def simulate_grid(preps: list, sweep: SweepConfig,
@@ -610,19 +633,24 @@ def simulate_grid(preps: list, sweep: SweepConfig,
     if batch_programs:
         arrays, spill0s, slots_used = _stack(preps)
         track_ab = any(p.num_folds for p in preps)
-        ctr, ctrA, ctrB = _dispatch_grid(machines, slots_used, track_ab,
-                                         arrays, spill0s, cfg, mach)
-        ctr, ctrA, ctrB = (np.asarray(x) for x in (ctr, ctrA, ctrB))
-    else:
-        outs = []
-        for prep in preps:
-            arrays, spill0s, slots_used = _stack([prep])
-            outs.append(_dispatch_grid(machines, slots_used,
-                                       prep.num_folds > 0, arrays, spill0s,
-                                       cfg, mach))
-        ctr = np.concatenate([np.asarray(o[0]) for o in outs])
-        ctrA = np.concatenate([np.asarray(o[1]) for o in outs])
-        ctrB = np.concatenate([np.asarray(o[2]) for o in outs])
+    c0 = _COMPILES
+    with dispatch_span("core", preps, sweep, machines,
+                       batch_programs) as sp:
+        if batch_programs:
+            ctr, ctrA, ctrB = _dispatch_grid(machines, slots_used, track_ab,
+                                             arrays, spill0s, cfg, mach)
+            ctr, ctrA, ctrB = (np.asarray(x) for x in (ctr, ctrA, ctrB))
+        else:
+            outs = []
+            for prep in preps:
+                arrays, spill0s, slots_used = _stack([prep])
+                outs.append(_dispatch_grid(machines, slots_used,
+                                           prep.num_folds > 0, arrays,
+                                           spill0s, cfg, mach))
+            ctr = np.concatenate([np.asarray(o[0]) for o in outs])
+            ctrA = np.concatenate([np.asarray(o[1]) for o in outs])
+            ctrB = np.concatenate([np.asarray(o[2]) for o in outs])
+        sp.set(compiled=_COMPILES != c0)
     if squeeze_m:
         ctr, ctrA, ctrB = ctr[:, :, 0], ctrA[:, :, 0], ctrB[:, :, 0]
     out = {k: ctr[..., i] for i, k in enumerate(COUNTER_NAMES)}
