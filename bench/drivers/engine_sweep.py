@@ -6,7 +6,9 @@ network, capacity x policy x L1 geometry).  Every sweep asks for a machine
 point no earlier sweep of the run asked for: the traffic file's ``machine``
 names one traced latency and its values, and the seed orders them.  The
 shapes never change, so nothing compiles after the first sweep, which is
-set-up.  The window ends at the first sweep completion after ``seconds``.
+set-up.  The window ends at the first sweep completion after ``seconds``,
+or earlier where the next sweep would need a machine point the traffic
+file lacks (``pool_exhausted``); either way it holds completed sweeps only.
 
 ``correct``: once the window has closed, one grid point per kernel, drawn
 from the seed among all the window's sweeps, is simulated again by the
@@ -217,7 +219,7 @@ def run(ctx) -> dict:
         session.run(sweeps.make(0))           # traces, folds, compiles
     ctx.mark_setup_done()
 
-    window, total_instr = [], 0
+    window, ends, total_instr, pool_exhausted = [], [], 0, 0
     traced_n = int(t.get("trace_sweeps", 1)) if ctx.trace else 0
     traced = dict(scan_steps=0, padded_rows=0, rows=0)
     c0 = ctx.compiles.count
@@ -240,18 +242,27 @@ def run(ctx) -> dict:
             if j == traced_n:
                 stack.close()
             elapsed = time.perf_counter() - t0
+            ends.append(round(elapsed, 3))
             if elapsed >= ctx.seconds:
+                break
+            if j + 1 == len(sweeps):
+                pool_exhausted = 1
+                ctx.log(f"window: the pool's {len(sweeps)} machine points "
+                        f"are used up after {elapsed:.3f} s of "
+                        f"{ctx.seconds} s")
                 break
             j += 1
     window_compiles = ctx.compiles.count - c0
     ctx.log(f"window: {len(window)} sweeps, {elapsed:.3f} s, "
             f"{total_instr} simulated instructions, "
-            f"{window_compiles} compiles inside")
+            f"{window_compiles} compiles inside; sweeps ended at {ends} s")
+    ctx.details["window"] = dict(seconds=elapsed, instructions=total_instr)
     peak = ctx.memory_peak()
     checks = check(ctx, session, window, reference)
     checks.append(("window_compiles", window_compiles, 0))
     return dict(
         attempted=len(window), failed=0, memory_peak_bytes=peak,
         end_to_end=dict(sim_instr_per_s=total_instr / elapsed),
-        counts=dict(traced, sweeps=len(window), traced_sweeps=traced_n),
+        counts=dict(traced, sweeps=len(window), traced_sweeps=traced_n,
+                    pool_exhausted=pool_exhausted),
         checks=checks)

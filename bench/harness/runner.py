@@ -13,7 +13,7 @@ import shutil
 import sys
 import time
 
-from harness import peaks, spec, spans, trace
+from harness import peaks, program_spans, spec, spans, trace
 
 OUT = spec.BENCH / "out"
 
@@ -84,6 +84,7 @@ def read_per_layer(ctx: Context, result: dict, dev: dict) -> tuple:
             f"and reduced in {time.perf_counter() - t0:.3f} s; lines "
             f"{ {k: v for k, v in tr.line_names.items() if 'device' in k} }")
     record = dict(trace=red, counts=result["counts"],
+                  program_spans=program_spans.recorded(),
                   config=ctx.cell.config, traffic=ctx.cell.traffic,
                   peaks=peaks.peaks(dev["kind"]), chips=ctx.cell.chips)
     metrics = {}

@@ -7,9 +7,10 @@ control on several seeds, in one process.
 For each seed this runs the cell's driver as ``bench/run.py`` does (set-up,
 window, check) and prints one JSON line with the numbers compared.  With
 ``--control`` the control stands in the program's place: for an engine
-cell the program's prefix-truncation path (``Sweep(max_events=...)``), for
-a served model the reference with fp8 weights, whose gap is read at the
-same positions as the program's.  The benchmark's own runs never run the
+cell the reference's answer for the nearest other memory latency of the
+pool (a stale answer from a results cache), for a served model the
+reference with fp8 weights, whose gap is read at the same positions as
+the program's.  The benchmark's own runs never run the
 control.  Needs the chip, like ``bench/run.py``.
 """
 
