@@ -11,8 +11,11 @@ time and compile time on lines of its own:
                at paper size, capacities {3, 4, 8, 32} x FIFO/LRU, plus a
                small cluster sweep (cores 1 and 4).  Every counter and
                ``fold_exact`` bit must equal the same sweeps run on the
-               host's CPU backend; the golden counter table and the
-               pathfinder capacity-3 pin must hold on the chip;
+               host's CPU backend, so the chip's single-core scan (the
+               Pallas kernel, which the phase requires and prints) is
+               checked against the CPU's XLA scan; the golden counter
+               table and the pathfinder capacity-3 pin must hold on the
+               chip;
   (c) kernels  the Pallas GEMMs (grouped W=1 and W=4, dispersed) at
                phi3-mini's FFN up-projection and causal flash attention at
                its attention shape, compiled (``interpret=False``) and
@@ -122,9 +125,19 @@ def _mismatches(got, want) -> list[str]:
     return bad + [k for k in got.data if k not in want.data]
 
 
+def _scan_impls(spans) -> dict:
+    """Engine (``core``, ``cluster``) -> the scans its recorded
+    ``engine.dispatch`` spans ran (``pallas`` or ``xla``)."""
+    impls: dict = {}
+    for s in spans:
+        if s.name == "engine.dispatch":
+            impls.setdefault(s.attrs["engine"], set()).add(s.attrs["impl"])
+    return {k: sorted(v) for k, v in impls.items()}
+
+
 def phase_engine() -> dict:
     import jax
-    from repro import api, rvv
+    from repro import api, obs, rvv
     from repro.core import policies, simulator
 
     kernels = list(rvv.PAPER_TABLE3)
@@ -136,19 +149,30 @@ def phase_engine() -> dict:
                          cores=CLUSTER_CORES, kernel_params="paper")
 
     chip = api.Session()
-    res, cold_s, cold_compile_s = _timed(chip.run, sweep)
-    # The second run reuses the prepared traces and the compiled engine:
-    # its wall time is dominated by the scan on the device (Session.run
-    # returns host arrays, so it ends after the device has finished).
-    res_warm, warm_s, _ = _timed(chip.run, sweep)
-    res_cl, cl_s, cl_compile_s = _timed(chip.run, cl_sweep)
+    obs.reset()
+    with obs.recording():
+        res, cold_s, cold_compile_s = _timed(chip.run, sweep)
+        # The second run reuses the prepared traces and the compiled
+        # engine: its wall time is dominated by the scan on the device
+        # (Session.run returns host arrays, so it ends after the device
+        # has finished).
+        res_warm, warm_s, _ = _timed(chip.run, sweep)
+        res_cl, cl_s, cl_compile_s = _timed(chip.run, cl_sweep)
+    chip_impl = _scan_impls(obs.spans())
 
-    with jax.default_device(_cpu()):
+    obs.reset()
+    with jax.default_device(_cpu()), obs.recording():
         host = api.Session(batch_programs=False)
         ref, ref_s, _ = _timed(host.run, sweep)
         ref_cl = host.run(cl_sweep)
+    cpu_impl = _scan_impls(obs.spans())
+    obs.reset()
+    print(f"engine scans: chip {chip_impl}, cpu {cpu_impl}", flush=True)
 
     failures = []
+    if chip_impl.get("core") != ["pallas"]:
+        failures.append(f"the chip ran {chip_impl.get('core')} for the "
+                        "single-core scan, not the Pallas kernel")
     for label, got, want in (("sweep", res, ref),
                              ("warm sweep", res_warm, ref),
                              ("cluster sweep", res_cl, ref_cl)):
@@ -185,6 +209,7 @@ def phase_engine() -> dict:
     return dict(
         ok=not failures, failures=failures,
         batch_programs=chip.batch_programs, kernels=len(kernels),
+        scan_impl=dict(chip=chip_impl, cpu=cpu_impl),
         points=res.meta["points"], cluster_points=res_cl.meta["points"],
         fold_exact_share=float(res.data["fold_exact"].mean()),
         golden_checked=len(golden), pathfinder_pin=pin,

@@ -207,7 +207,7 @@ def simulate_cluster_grid(preps: list, sweep: SweepConfig,
         track_ab = any(p.num_folds for p in preps)
     c0 = simulator._COMPILES
     with simulator.dispatch_span("cluster", preps, sweep, machines,
-                                 batch_programs) as sp:
+                                 batch_programs, "xla") as sp:
         if batch_programs:
             ctr, ctrA, ctrB = _dispatch_cluster_grid(
                 cluster, machines, slots_used, track_ab, arrays, spill0s,

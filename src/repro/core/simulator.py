@@ -22,6 +22,8 @@ each — the full design narrative lives in ``docs/architecture.md``:
   * **Batched (P, C, M) sweep grid**: :func:`simulate_grid` vmaps programs
     x configs x traced machine-latency points (:class:`MachineSweep`) into
     one dispatch, compiled once per power-of-two program-shape bucket.
+  * **On the TPU the scan is one Pallas kernel** (``core.scan_tpu``), bit
+    for bit the XLA scan, which the CPU and the cluster engine run.
   * **Exact periodic folding** (``core.folding``): warm-up + two measured
     periods per hot loop, algebraic extrapolation, with the A == B
     ``fold_exact`` certificate evaluated per (C, M) grid point — see
@@ -44,7 +46,7 @@ import numpy as np
 
 from repro import obs
 from repro.core import events as ev_mod
-from repro.core import folding, isa, policies
+from repro.core import folding, isa, policies, scan_tpu
 from repro.core.events import NO_NEXT_USE, EventStream
 from repro.core.trace import Program
 
@@ -415,6 +417,30 @@ def _run_grid(l1_sets, l1_ways, slots_used, track_ab, arrays, spill0s,
     return jax.vmap(one_program)(arrays, spill0s)
 
 
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3),
+                   static_argnames=("interpret",), donate_argnums=(4, 5))
+def _run_grid_pallas(l1_sets, l1_ways, slots_used, track_ab, arrays,
+                     spill0s, cfg, mach, interpret=False):
+    """:func:`_run_grid` as one Pallas TPU kernel (``core.scan_tpu``):
+    the same inputs, the same counters bit for bit, the same compile
+    accounting (one per static signature and shape bucket)."""
+    global _COMPILES
+    _COMPILES += 1
+    return scan_tpu.scan_grid(l1_sets, l1_ways, slots_used, track_ab,
+                              arrays, spill0s, cfg, mach,
+                              interpret=interpret)
+
+
+def scan_impl(machine: MachineSweep, cfg) -> str:
+    """The scan a dispatch runs: ``"pallas"`` where the engine's arrays
+    live on a TPU and the kernel's L1 planes fit its VMEM, else the XLA
+    body (``"xla"``), which the CPU and the cluster engine always run."""
+    platform = next(iter(cfg[0].devices())).platform
+    fits = (scan_tpu.l1_bytes(machine.l1_sets, machine.l1_ways)
+            <= scan_tpu.MAX_L1_BYTES)
+    return "pallas" if platform == "tpu" and fits else "xla"
+
+
 # ---------------------------------------------------------------------------
 # Trace preparation: expansion + optional periodic folding / truncation.
 # ---------------------------------------------------------------------------
@@ -567,28 +593,33 @@ def _stack(preps: list[PreparedTrace], pad_to: int | None = None):
 
 
 def _dispatch_grid(machine: MachineSweep, slots_used, track_ab, arrays,
-                   spill0s, cfg, mach):
-    """One `_run_grid` call with donation noise suppressed: the counter
-    outputs are far smaller than the donated trace grid, so XLA may decline
-    the alias and warn — harmless, the donation is an upper bound."""
+                   spill0s, cfg, mach, impl):
+    """One `_run_grid` (``impl="xla"``) or `_run_grid_pallas` call with
+    donation noise suppressed: the counter outputs are far smaller than
+    the donated trace grid, so XLA may decline the alias and warn —
+    harmless, the donation is an upper bound."""
     global _DISPATCHES
     _DISPATCHES += 1
+    run = _run_grid_pallas if impl == "pallas" else _run_grid
     with warnings.catch_warnings():
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
-        return _run_grid(machine.l1_sets, machine.l1_ways, slots_used,
-                         track_ab, tuple(jnp.asarray(a) for a in arrays),
-                         jnp.asarray(spill0s), cfg, mach)
+        return run(machine.l1_sets, machine.l1_ways, slots_used, track_ab,
+                   tuple(jnp.asarray(a) for a in arrays),
+                   jnp.asarray(spill0s), cfg, mach)
 
 
 def dispatch_span(engine: str, preps: list, sweep: SweepConfig,
-                  machines: MachineSweep, fused: bool):
+                  machines: MachineSweep, fused: bool, impl: str):
     """The ``engine.dispatch`` span of one engine call, from the call to
     its counters on the host (every call blocks on them, so the span
     covers the device's work).  ``steps`` counts bucket rows scanned: once
     for a fused dispatch, whose lanes advance together, once per program
-    on the per-program path, whose stacks then nest inside the span."""
-    sp = obs.span("engine.dispatch", engine=engine)
+    on the per-program path, whose stacks then nest inside the span.
+    ``impl`` names the scan that ran (``"pallas"`` or ``"xla"``), and
+    ``l1_sets`` the L1 geometry the kernel's spill window scales with."""
+    sp = obs.span("engine.dispatch", engine=engine, impl=impl,
+                  l1_sets=machines.l1_sets)
     if sp:
         buckets = [_bucket(p.num_rows) for p in preps]
         sp.set(steps=max(buckets) if fused else sum(buckets),
@@ -630,15 +661,17 @@ def simulate_grid(preps: list, sweep: SweepConfig,
     mach = (jnp.asarray(machines.l1_hit_cycles),
             jnp.asarray(machines.uop_hit_cycles),
             jnp.asarray(machines.mem_latency))
+    impl = scan_impl(machines, cfg)
     if batch_programs:
         arrays, spill0s, slots_used = _stack(preps)
         track_ab = any(p.num_folds for p in preps)
     c0 = _COMPILES
     with dispatch_span("core", preps, sweep, machines,
-                       batch_programs) as sp:
+                       batch_programs, impl) as sp:
         if batch_programs:
             ctr, ctrA, ctrB = _dispatch_grid(machines, slots_used, track_ab,
-                                             arrays, spill0s, cfg, mach)
+                                             arrays, spill0s, cfg, mach,
+                                             impl)
             ctr, ctrA, ctrB = (np.asarray(x) for x in (ctr, ctrA, ctrB))
         else:
             outs = []
@@ -646,7 +679,7 @@ def simulate_grid(preps: list, sweep: SweepConfig,
                 arrays, spill0s, slots_used = _stack([prep])
                 outs.append(_dispatch_grid(machines, slots_used,
                                            prep.num_folds > 0, arrays,
-                                           spill0s, cfg, mach))
+                                           spill0s, cfg, mach, impl))
             ctr = np.concatenate([np.asarray(o[0]) for o in outs])
             ctrA = np.concatenate([np.asarray(o[1]) for o in outs])
             ctrB = np.concatenate([np.asarray(o[2]) for o in outs])

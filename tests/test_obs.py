@@ -107,6 +107,8 @@ def _check_run(spans, meta, *, fused, geometries):
     assert sum(s.attrs["dispatches"] for s in dispatch) == \
         meta["dispatches"]
     assert all(s.attrs["engine"] == "core" for s in dispatch)
+    # The CPU runs the XLA scan; the Pallas kernel is the TPU's.
+    assert all(s.attrs["impl"] == "xla" for s in dispatch)
     refine_ids = {s.id for s in by_name(spans, "session.refine")}
     planned = [s for s in dispatch if s.parent not in refine_ids]
     want = [e["bucket"] * (1 if fused else len(e["kernels"]))
@@ -137,6 +139,8 @@ def test_session_run_spans(fused):
     assert all(s.attrs["bytes"] > 0 for s in stacks)
     for s in by_name(spans, "engine.dispatch"):
         assert s.attrs["lanes"] == s.attrs["programs"] * 2 * 2
+    assert sorted({s.attrs["l1_sets"]
+                   for s in by_name(spans, "engine.dispatch")}) == [128, 256]
     # A second run of the same sweep prepares nothing and compiles nothing.
     obs.reset()
     with obs.recording():
@@ -175,6 +179,7 @@ def test_cluster_dispatch_span():
                                 cores=(2,), kernel_params="reduced"))
     (d,) = by_name(obs.spans(), "engine.dispatch")
     assert d.attrs["engine"] == "cluster"
+    assert d.attrs["impl"] == "xla" and d.attrs["l1_sets"] == 256
     assert d.attrs["dispatches"] == res.meta["dispatches"] == 1
     assert d.attrs["steps"] == res.meta["plan"][0]["bucket"]
 

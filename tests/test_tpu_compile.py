@@ -4,7 +4,8 @@ Nothing runs: each test lowers a hot-path program with shapes placed on
 one chip of a described ``v5e:2x2`` topology and compiles it with the
 TPU compiler, which refuses what the chip would refuse (scoped VMEM,
 tiling, HBM capacity).  The widths are ``chip_smoke.py``'s: phi3-mini's
-FFN up-projection and attention, one paper-size engine bucket, and the
+FFN up-projection and attention, one paper-size engine bucket, the
+engine's Pallas scan at the benchmark's dispatch shapes, and the
 full-width phi3-mini decode step.
 
 The topology is described inside a fixture (only the worker that runs
@@ -107,6 +108,38 @@ def test_engine_bucket_compiles(one_chip):
                                 sweep.alloc_no_fetch)),
         tuple(spec(x) for x in (mach.l1_hit_cycles, mach.uop_hit_cycles,
                                 mach.mem_latency))).compile()
+
+
+# The Pallas scan at the benchmark's dispatch shapes (programs, bucket
+# rows, configs, L1 sets): cell 1's largest bucket, a folded paper-size
+# trace at 8 capacities x 2 policies, and cell 3's 16 KB dispatch of four
+# phi3-mini tile programs at 5 capacities.
+SCAN_DISPATCHES = {
+    "capacity-sweep-131072": (1, 131072, 16, 256, True),
+    "rvv-network-16kb": (4, 16384, 5, 256, False),
+}
+
+
+@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
+@pytest.mark.parametrize("name", sorted(SCAN_DISPATCHES))
+def test_scan_kernel_compiles(one_chip, name):
+    """The single-core scan as one Pallas kernel: its SMEM trace blocks
+    and VMEM state fit what the chip's compiler allows."""
+    programs, rows, configs, l1_sets, track_ab = SCAN_DISPATCHES[name]
+    b = rvv.BENCHMARKS["gemv"]
+    arrays, _, _ = simulator._stack(
+        [simulator.prepare(b.build(**b.reduced_params).program)])
+
+    def spec(shape, dtype=jnp.int32):
+        return _spec(one_chip, shape, dtype)
+
+    compiled = simulator._run_grid_pallas.lower(
+        l1_sets, 2, (True,) * 5, track_ab,
+        tuple(spec((programs, rows) + a.shape[2:], a.dtype) for a in arrays),
+        spec((programs,)),
+        (spec((configs,)), spec((configs,)), spec((configs,), jnp.bool_)),
+        tuple(spec((1,)) for _ in range(3))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_phi3_decode_step_fits_one_chip(one_chip):
