@@ -206,7 +206,9 @@ class Sweep:
     the legacy truncation budget (forces ``fold`` off) — kept as an explicit
     escape hatch for smoke runs; prefer folding.
 
-    ``network`` names models from :mod:`repro.configs.registry`: each is
+    ``network`` names models from :mod:`repro.configs.registry` (a bare
+    name lowers a prefill token block, ``"<model>:decode:batch=B:..."``
+    one decode step, :func:`repro.bridge.network.parse_spec`): each is
     lowered through :mod:`repro.bridge` (layer shapes -> deduplicated
     ``net:*`` kernels, registered on first use) and the union of lowered
     kernels joins the ``kernel`` axis — so one ``Sweep(network=(...,))``
@@ -715,6 +717,18 @@ class SweepResult:
 # ---------------------------------------------------------------------------
 
 
+def _family_rows(preps: dict) -> dict:
+    """``session.prepare`` span counters: the prepared scan rows of every
+    kernel family (a ``net:<kind>:...`` kernel's ``net:<kind>``, any other
+    kernel's own name) as ``rows:<family>``, and their sum as ``rows``."""
+    out: dict = {}
+    for name, prep in preps.items():
+        key = "rows:" + ":".join(name.split(":")[:2])
+        out[key] = out.get(key, 0) + prep.num_rows
+    out["rows"] = sum(out.values())
+    return out
+
+
 class Session:
     """Owns every sweep-side cache and executes :class:`Sweep` specs.
 
@@ -992,6 +1006,8 @@ class Session:
                      for n in names}
             misses = len(self._prepared) - n0
             sp.set(hits=len(names) - misses, misses=misses)
+            if sp:
+                sp.set(**_family_rows(preps))
         groups: dict[int, list[str]] = {}
         for n in names:
             bucket = simulator._bucket(preps[n].num_rows)

@@ -5,7 +5,9 @@ Pipeline (one module per stage, see docs/bridge.md for the contract):
 - :mod:`repro.bridge.shapes` — walk a :mod:`repro.configs.registry` model,
   extract every layer's concrete shapes via the :mod:`repro.models` init
   functions (``jax.eval_shape``, no parameter memory), emit
-  :class:`LayerOp` records (gemm / attn / scan) with network-level counts.
+  :class:`LayerOp` records (gemm / attn / scan) with network-level counts;
+  ``decode_ops`` lowers one decode step instead (``dgemm`` / ``expert``
+  skinny GEMMs at the step's rows, absorbed-MLA ``mla``).
 - :mod:`repro.bridge.lower` — lower each op kind to a fixed-shape tile
   program built from ``Assembler.repeat`` deep nests with way-span-padded
   planes, so the outer loops certify exact under :mod:`repro.core.folding`.
@@ -13,7 +15,8 @@ Pipeline (one module per stage, see docs/bridge.md for the contract):
   one benchmark per unique signature (``net:*`` names, domain
   ``"network"``), and report per-model totals from per-kernel sweeps.
 
-Front door: ``Sweep(network=("granite-8b", ...))`` in :mod:`repro.api`.
+Front door: ``Sweep(network=("granite-8b", ...))`` in :mod:`repro.api`;
+``"<model>:decode:batch=B:context=L:zipf=S:seed=N"`` names a decode step.
 """
 
 from repro.bridge.shapes import TOKEN_BLOCK, LayerOp, model_ops

@@ -14,6 +14,12 @@ benchmarks, a whole model becomes one ``Sweep(kernels=net.kernels, ...)``
 totals: each unit's counters scale by ``count * macro_factor`` (tile
 programs cover a fixed sub-problem; the macro factor is real-work /
 tile-work, see :mod:`.lower`).
+
+A network spec names a model and, optionally, its phase: ``"granite-8b"``
+is a prefill token block (:func:`.shapes.model_ops`);
+``"deepseek-v2-lite-16b:decode:batch=8:context=4096:zipf=1.0:seed=0"`` is
+one decode step (:func:`.shapes.decode_ops`), every option of
+:data:`DECODE_OPTIONS` stated.
 """
 
 from __future__ import annotations
@@ -30,7 +36,7 @@ class NetworkUnit:
     """One deduplicated layer group of a lowered network."""
 
     kernel: str           # registered benchmark name (net:<kind>:<shape>)
-    kind: str             # gemm | attn | scan
+    kind: str             # gemm | attn | scan | dgemm | expert | mla
     labels: tuple         # layer labels merged into this unit
     shape: tuple          # real layer shape (signature dims)
     count: int            # instances across the network
@@ -72,12 +78,60 @@ def _register(name: str, kind: str, kwargs: dict, op) -> None:
     )(lower.builder_for(kind))
 
 
-def lower_network(model: str) -> LoweredNetwork:
-    """Lower registry model ``model``; idempotent (re-lowering a model, or
-    lowering two models sharing a layer shape, reuses registered kernels).
+#: Options of a decode spec, ``<model>:decode:<key>=<value>...``, each
+#: required, and their types: the sequences in the step, the positions
+#: each has cached, and the routing draw's Zipf exponent and seed
+#: (:func:`.shapes.routing_loads`).
+DECODE_OPTIONS = dict(batch=int, context=int, zipf=float, seed=int)
+
+
+def parse_spec(spec: str) -> tuple[str, dict | None]:
+    """(model, decode options), or (model, None) for a prefill spec."""
+    model, sep, rest = spec.partition(":")
+    if not sep:
+        return model, None
+    phase, *opts = rest.split(":")
+    if phase != "decode":
+        raise ValueError(f"network spec {spec!r}: unknown phase {phase!r} "
+                         f"(only 'decode' may follow the model)")
+    params = {}
+    for opt in opts:
+        key, eq, value = opt.partition("=")
+        if not eq or key not in DECODE_OPTIONS:
+            raise ValueError(f"network spec {spec!r}: bad option {opt!r}; "
+                             f"options are {sorted(DECODE_OPTIONS)}")
+        params[key] = DECODE_OPTIONS[key](value)
+    missing = sorted(DECODE_OPTIONS.keys() - params.keys())
+    if missing:
+        raise ValueError(f"network spec {spec!r}: missing options "
+                         f"{missing}")
+    return model, params
+
+
+def _decode_counts(layer_ops) -> dict:
+    """``bridge.lower`` span counters of a decode lowering: instances of
+    each decode kind, and the tokens of the busiest and idlest active
+    routed expert."""
+    out = {f"{kind}_ops": sum(o.count for o in layer_ops if o.kind == kind)
+           for kind in ("dgemm", "expert", "mla")}
+    routed = [o.shape[0] for o in layer_ops
+              if o.label.startswith("moe/routed/")]
+    if routed:
+        out.update(expert_tokens_max=max(routed),
+                   expert_tokens_min=min(routed))
+    return out
+
+
+def lower_network(spec: str) -> LoweredNetwork:
+    """Lower the network ``spec`` names (a registry model, optionally with
+    ``:decode`` options, see :func:`parse_spec`); idempotent (re-lowering
+    a model, or lowering two models sharing a layer shape, reuses
+    registered kernels).
     """
-    with obs.span("bridge.lower", model=model) as sp:
-        layer_ops = shapes.model_ops(model)
+    with obs.span("bridge.lower", model=spec) as sp:
+        model, decode = parse_spec(spec)
+        layer_ops = (shapes.model_ops(model) if decode is None
+                     else shapes.decode_ops(model, **decode))
         groups: dict[tuple, list] = {}
         for op in layer_ops:
             groups.setdefault(op.signature, []).append(op)
@@ -90,8 +144,10 @@ def lower_network(model: str) -> LoweredNetwork:
                 labels=tuple(o.label for o in ops), shape=tuple(sig[1:]),
                 count=sum(o.count for o in ops), macro_factor=macro,
                 params=dict(kwargs)))
-        net = LoweredNetwork(model=model, units=tuple(units))
+        net = LoweredNetwork(model=spec, units=tuple(units))
         sp.set(kernels=len(net.kernels), ops=len(layer_ops))
+        if decode is not None and sp:
+            sp.set(**_decode_counts(layer_ops))
     return net
 
 

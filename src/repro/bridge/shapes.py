@@ -15,6 +15,12 @@ GEMM processes TOKEN_BLOCK rows, attention covers a TOKEN_BLOCK-long
 context, and recurrences run TOKEN_BLOCK steps.  Lowered tiles cover a
 fixed sub-problem; the ratio real-work / tile-work is the op's macro
 factor (see :mod:`repro.bridge.lower`).
+
+:func:`decode_ops` lowers the other phase: one decode step of ``batch``
+sequences, each ``context`` positions into its cache.  Every GEMM then has
+the step's tokens as its rows (``dgemm``), routed experts have the tokens
+a seeded routing draw gives them (``expert``), and latent attention (MLA)
+reads the compressed cache with its up-projections absorbed (``mla``).
 """
 
 from __future__ import annotations
@@ -24,6 +30,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs import registry
 from repro.models.attention import init_attention, init_mla
@@ -52,6 +59,9 @@ class LayerOp:
     ``(heads, head_dim)``) or ``scan`` (shape ``(width,)``: elementwise
     recurrence over ``width`` channels).  ``count`` is how many instances
     the whole network runs per token block (layers x multiplicity).
+    Decode-phase kinds (:func:`decode_ops`) carry their rows in the shape
+    and count instances per decode step: ``dgemm`` and ``expert`` ``(M, K,
+    N)``, ``mla`` ``(batch, heads, nope, rope, latent, vdim, context)``.
     """
 
     kind: str
@@ -75,8 +85,23 @@ class LayerOp:
         if self.kind == "attn":
             heads, hd = self.shape
             return 2 * TOKEN_BLOCK * TOKEN_BLOCK * hd * heads
+        if self.kind in ("dgemm", "expert"):
+            m, k, n = self.shape
+            return m * k * n
+        if self.kind == "mla":
+            return mla_macs(*self.shape)
         (width,) = self.shape
         return TOKEN_BLOCK * width
+
+
+def mla_macs(batch, heads, nope, rope, latent, vdim, context) -> int:
+    """MACs of one absorbed-MLA decode step: per sequence and head, the
+    query absorbed into the latent space (nope x latent), scores against
+    the latent and rope cache (context x (latent + rope)), the weighted
+    latent sum (context x latent) and the value up-projection (latent x
+    vdim)."""
+    return batch * heads * (nope * latent + context * (latent + rope)
+                            + context * latent + latent * vdim)
 
 
 def _weight_shapes(init_fn, *args, **kwargs) -> list[tuple[str, tuple]]:
@@ -181,4 +206,92 @@ def model_ops(model: str) -> tuple[LayerOp, ...]:
     elif cfg.frontend == "vision":       # patch embedding, im2col
         ops.append(LayerOp("gemm", "frontend/patch", (_VISION_PATCH, d), 1))
     ops.append(LayerOp("gemm", "lm_head", (d, cfg.vocab_size), 1))
+    return tuple(ops)
+
+
+# ---------------------------------------------------------------------------
+# Decode phase.
+# ---------------------------------------------------------------------------
+
+
+def routing_loads(num_experts: int, top_k: int, tokens: int, layers: int,
+                  zipf: float, seed: int) -> list[np.ndarray]:
+    """Tokens each routed expert receives, per MoE layer, under a seeded
+    Zipf-skewed expert popularity.
+
+    One generator ``numpy.random.default_rng(seed)`` serves every layer
+    in order.  Per layer: ``rank = rng.permutation(num_experts)`` ranks
+    the experts, expert ``e`` has popularity ``1 / (rank[e] + 1) ** zipf``
+    (normalised), and each token in turn picks ``top_k`` distinct experts
+    by ``rng.choice(num_experts, top_k, replace=False, p=popularity)``.
+    The draw depends on its arguments alone, never on a run's seed, so
+    every sweep lowers the same work.  Each layer's loads add up to
+    ``tokens * top_k``.
+    """
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(layers):
+        rank = rng.permutation(num_experts)
+        pop = 1.0 / (rank + 1.0) ** zipf
+        pop /= pop.sum()
+        picks = [rng.choice(num_experts, top_k, replace=False, p=pop)
+                 for _ in range(tokens)]
+        out.append(np.bincount(np.concatenate(picks),
+                               minlength=num_experts))
+    return out
+
+
+@functools.lru_cache(maxsize=None)
+def decode_ops(model: str, batch: int, context: int, zipf: float,
+               seed: int) -> tuple[LayerOp, ...]:
+    """LayerOps of one decode step of registry model ``model``: ``batch``
+    sequences, one new token each, ``context`` positions cached.
+
+    Latent attention (``cfg.mla``) only: its projections at M = batch
+    (``wq``, ``wdkv``, ``wkr``, ``wo``; the key/value up-projection is
+    absorbed, not run) and one ``mla`` op over the latent cache per layer.
+    The MLP of each dense layer, the router, the shared experts (one MLP
+    of ``num_shared_experts`` x ``moe_d_ff``) and the LM head run at M =
+    batch; each routed expert runs at the tokens :func:`routing_loads`
+    gives it, and an expert that gets none emits nothing.
+    """
+    cfg = registry.get(model)
+    if not cfg.mla:
+        raise ValueError(
+            f"decode lowering needs latent attention (MLA); {model!r} has "
+            f"{cfg.num_heads}-head attention, whose decode tile does not "
+            f"exist yet")
+    d, l, b = cfg.d_model, cfg.num_layers, batch
+    h, dn, dr = cfg.num_heads, cfg.qk_nope_dim, cfg.qk_rope_dim
+    r, dv = cfg.kv_lora_rank, cfg.v_head_dim
+    ops = [LayerOp("dgemm", "attn/wq", (b, d, h * (dn + dr)), l),
+           LayerOp("dgemm", "attn/wdkv", (b, d, r), l),
+           LayerOp("dgemm", "attn/wkr", (b, d, dr), l),
+           LayerOp("mla", "attention", (b, h, dn, dr, r, dv, context), l),
+           LayerOp("dgemm", "attn/wo", (b, h * dv, d), l)]
+    n_dense = cfg.first_dense_layers if cfg.moe else l
+    if n_dense:
+        ops += [LayerOp("dgemm", "mlp/wg", (b, d, cfg.d_ff), n_dense),
+                LayerOp("dgemm", "mlp/wi", (b, d, cfg.d_ff), n_dense),
+                LayerOp("dgemm", "mlp/wo", (b, cfg.d_ff, d), n_dense)]
+    n_moe = l - n_dense
+    if n_moe:
+        ff = cfg.moe_d_ff
+        ops.append(LayerOp("dgemm", "moe/router", (b, d, cfg.num_experts),
+                           n_moe))
+        if cfg.num_shared_experts:
+            sff = ff * cfg.num_shared_experts
+            ops += [LayerOp("expert", "moe/shared/wg", (b, d, sff), n_moe),
+                    LayerOp("expert", "moe/shared/wi", (b, d, sff), n_moe),
+                    LayerOp("expert", "moe/shared/wo", (b, sff, d), n_moe)]
+        loads = routing_loads(cfg.num_experts, cfg.moe_top_k, b, n_moe,
+                              zipf, seed)
+        for i, per_expert in enumerate(loads):
+            for e in np.flatnonzero(per_expert):
+                m = int(per_expert[e])
+                tag = f"moe/routed/l{i}e{e}"
+                ops += [LayerOp("expert", f"{tag}/wg", (m, d, ff), 1),
+                        LayerOp("expert", f"{tag}/wi", (m, d, ff), 1),
+                        LayerOp("expert", f"{tag}/wo", (m, ff, d), 1)]
+    ops.append(LayerOp("dgemm", "lm_head", (b, d, cfg.vocab_size), 1))
     return tuple(ops)

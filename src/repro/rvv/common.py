@@ -122,24 +122,27 @@ def check(built: Built, memory: np.ndarray) -> None:
 # identical in the trace and the numpy reference).
 
 EXP_SQUARINGS = 5
-EXP_DENOM = float(2 ** EXP_SQUARINGS)
 EXP_CLAMP = -60.0
 
 
-def emit_exp(a: Assembler, r: int, r_clamp: int) -> None:
+def emit_exp(a: Assembler, r: int, r_clamp: int,
+             squarings: int = EXP_SQUARINGS) -> None:
     """In-place exp approximation of register ``r``; ``r_clamp`` must hold
-    broadcast EXP_CLAMP. Exercises the v0 mask path (vmslt + vmerge)."""
+    broadcast EXP_CLAMP. Exercises the v0 mask path (vmslt + vmerge).
+    ``exp(x) ~= (1 + x / 2**squarings) ** (2**squarings)``: more squarings,
+    a closer exp (relative error about x**2 / 2**(squarings + 1))."""
     a.vmslt(r, r_clamp)            # v0 = (x < -60)
     a.vmerge(r, r_clamp, r)        # x = v0 ? -60 : x
-    a.vmul_sc(r, r, 1.0 / EXP_DENOM)
+    a.vmul_sc(r, r, 1.0 / 2 ** squarings)
     a.vadd_sc(r, r, 1.0)
-    for _ in range(EXP_SQUARINGS):
+    for _ in range(squarings):
         a.vmul(r, r, r)
 
 
-def np_exp_approx(x: np.ndarray) -> np.ndarray:
+def np_exp_approx(x: np.ndarray,
+                  squarings: int = EXP_SQUARINGS) -> np.ndarray:
     x = np.maximum(x, EXP_CLAMP)
-    t = 1.0 + x / EXP_DENOM
-    for _ in range(EXP_SQUARINGS):
+    t = 1.0 + x / 2 ** squarings
+    for _ in range(squarings):
         t = t * t
     return t

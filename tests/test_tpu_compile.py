@@ -112,11 +112,15 @@ def test_engine_bucket_compiles(one_chip):
 
 # The Pallas scan at the benchmark's dispatch shapes (programs, bucket
 # rows, configs, L1 sets): cell 1's largest bucket, a folded paper-size
-# trace at 8 capacities x 2 policies, and cell 3's 16 KB dispatch of four
-# phi3-mini tile programs at 5 capacities.
+# trace at 8 capacities x 2 policies, cell 3's 16 KB dispatch of four
+# phi3-mini tile programs at 5 capacities, and the decode cell's widest
+# and longest dispatches: 17 folded skinny-GEMM tiles on the 16 KB L1, and
+# the folded absorbed-MLA tile alone.
 SCAN_DISPATCHES = {
     "capacity-sweep-131072": (1, 131072, 16, 256, True),
     "rvv-network-16kb": (4, 16384, 5, 256, False),
+    "rvv-decode-16kb": (17, 32768, 5, 256, True),
+    "rvv-decode-mla": (1, 524288, 5, 256, True),
 }
 
 
