@@ -736,6 +736,9 @@ class Session:
     * *prepared* traces (expanded + folded / truncated), keyed (name,
       params, fold, max_events, fold warm-up — a function of the static L1
       geometry only);
+    * *resident* scan inputs: each bucket group's packed traces on the
+      device, copied by the first dispatch that needs them and read by
+      every later sweep (:class:`simulator.ResidentTraces`);
     * the fold / refine policy (``refine`` transparently re-simulates
       uncertified folds without folding when the full trace is affordable);
     * compile / dispatch accounting for every engine call it issued
@@ -764,6 +767,7 @@ class Session:
         self.history: list[dict] = []
         self._built: dict = {}
         self._prepared: dict = {}
+        self._resident = simulator.ResidentTraces()
         self._compiles = 0
         self._dispatches = 0
 
@@ -822,6 +826,7 @@ class Session:
         """Drop every cache and counter (the jit cache is XLA's, not ours)."""
         self._built.clear()
         self._prepared.clear()
+        self._resident.clear()
         self.history.clear()
         self._compiles = 0
         self._dispatches = 0
@@ -840,7 +845,8 @@ class Session:
     def _simulate(self, preps, config, machine):
         c0, d0 = simulator.compile_count(), simulator.dispatch_count()
         out = simulator.simulate_grid(preps, config, machine,
-                                      batch_programs=self.batch_programs)
+                                      batch_programs=self.batch_programs,
+                                      resident=self._resident)
         self._compiles += simulator.compile_count() - c0
         self._dispatches += simulator.dispatch_count() - d0
         return out

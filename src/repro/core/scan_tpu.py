@@ -26,6 +26,11 @@ same counters bit for bit, without leaving the core:
 The counters that depend only on the trace (instruction cost, register
 and memory reads and writes) are sums over rows and are taken outside the
 kernel; the kernel accumulates the eight per-lane ones.
+
+The kernel's inputs are built on the host (:func:`pack`,
+:func:`lane_params`): the row words and those sums once per bucket group,
+which the engine then keeps on the device across sweeps, and one small
+lane-parameter array per dispatch.
 """
 
 from __future__ import annotations
@@ -34,6 +39,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -296,10 +302,12 @@ def _kernel(spill0_ref, trace_ref, lane_ref, out_ref, meta_ref, l1_ref,
 
 
 def _pack_rows(arrays):
-    """The 15 trace arrays of ``_run_grid`` as (P, T, NUM_FIELDS) int32."""
+    """The 15 trace arrays of ``_run_grid``, one program's ``(T, ...)`` or a
+    stack's ``(P, T, ...)``, as ``(..., T, NUM_FIELDS)`` int32 row words on
+    the host."""
     (rv, rg, vdw, vdr, vdnf, lk1, lk2, mv, ml, mw, cost, nxt,
-     wt, wa, wb) = arrays
-    i32 = lambda x: x.astype(jnp.int32)
+     wt, wa, wb) = (np.asarray(a) for a in arrays)
+    i32 = lambda x: x.astype(np.int32)
     flags = (i32(rv[..., 0]) << _RV | i32(rv[..., 1]) << (_RV + 1)
              | i32(rv[..., 2]) << (_RV + 2) | i32(vdw) << _VDW
              | i32(vdr) << _VDR | i32(vdnf) << _VDNF
@@ -308,59 +316,82 @@ def _pack_rows(arrays):
     cols = [flags, rg[..., 0], rg[..., 1], rg[..., 2], lk1, lk2,
             ml[..., 0], ml[..., 1], cost, nxt[..., 0], nxt[..., 1],
             nxt[..., 2], wt, wa, wb]
-    return jnp.stack([i32(c) for c in cols], axis=-1)
+    return np.stack([i32(c) for c in cols], axis=-1)
 
 
-def _trace_counters(arrays, slots_used, weight):
-    """(P, 5) sums over rows of ``weight`` x (cost, reg reads, reg writes,
-    mem reads, mem writes): the counters no lane state decides."""
+def _trace_counters(arrays, weight):
+    """``(..., 5)`` sums over rows of ``weight`` x (cost, reg reads, reg
+    writes, mem reads, mem writes): the counters no lane state decides.
+    Summed in int64 and wrapped to int32, which is the scan's int32
+    accumulation bit for bit, overflow included."""
     (rv, _rg, vdw, vdr, _vdnf, _lk1, _lk2, mv, _ml, mw, cost, _nxt,
-     *_w) = arrays
-    i32 = lambda x: x.astype(jnp.int32)
-    zero = jnp.zeros_like(cost)
-    rr = sum((i32(rv[..., s]) for s in range(2) if slots_used[s]), zero)
-    rw = zero
-    if slots_used[2]:
-        rr = rr + i32(rv[..., 2] & vdr)
-        rw = i32(rv[..., 2] & vdw)
-    mr = sum((i32(mv[..., m] & ~mw[..., m]) for m in range(2)
-              if slots_used[3 + m]), zero)
-    mwr = sum((i32(mv[..., m] & mw[..., m]) for m in range(2)
-               if slots_used[3 + m]), zero)
-    per_row = jnp.stack([cost, rr, rw, mr, mwr], axis=-1)
-    return jnp.sum(per_row * weight[..., None], axis=1)
+     *_w) = (np.asarray(a) for a in arrays)
+    i64 = lambda x: x.astype(np.int64)
+    per_row = np.stack([
+        i64(cost),
+        i64(rv[..., 0]) + i64(rv[..., 1]) + i64(rv[..., 2] & vdr),
+        i64(rv[..., 2] & vdw),
+        i64(mv[..., 0] & ~mw[..., 0]) + i64(mv[..., 1] & ~mw[..., 1]),
+        i64(mv[..., 0] & mw[..., 0]) + i64(mv[..., 1] & mw[..., 1]),
+    ], axis=-1)
+    weight = i64(np.asarray(weight))[..., None]
+    return (per_row * weight).sum(axis=-2).astype(np.int32)
 
 
-def scan_grid(l1_sets, l1_ways, slots_used, track_ab, arrays, spill0s, cfg,
-              mach, *, interpret=False):
-    """``_run_grid``'s scan as one kernel: same inputs, same ``(P, C, M,
-    12)`` ``ctr``, ``ctrA`` and ``ctrB``.  Traceable; the caller jits."""
-    capacity, policy, anf = cfg
-    l1_hit, uop_hit, mem_lat = mach
-    n_cfg, n_mach = capacity.shape[0], mem_lat.shape[0]
-    n_lanes = n_cfg * n_mach
+def pack(programs, fills, rows: int):
+    """The kernel's inputs of a bucket group, built once on the host.
+
+    ``programs`` holds each program's 15 trace arrays, unpadded, and
+    ``fills`` the value of a padding row in each.  Returns the row words,
+    every program padded with the padding row to ``rows`` (rounded up to
+    whole row blocks) and flattened P-major, ``(P * rows * NUM_FIELDS,)``
+    int32; and the trace-only counter sums, ``(3, P, 5)`` int32 for the
+    weights wt, wa and wb (padding rows weigh 0)."""
+    rows = pl.cdiv(rows, ROW_BLOCK) * ROW_BLOCK
+    pad = _pack_rows(tuple(np.full((1,) + a.shape[1:], f, a.dtype)
+                           for a, f in zip(programs[0], fills)))
+    words = np.empty((len(programs), rows, NUM_FIELDS), np.int32)
+    words[:] = pad
+    sums = np.empty((3, len(programs), 5), np.int32)
+    for p, arrays in enumerate(programs):
+        words[p, :len(arrays[0])] = _pack_rows(arrays)
+        for a in range(3):
+            sums[a, p] = _trace_counters(arrays, arrays[12 + a])
+    return words.reshape(-1), sums
+
+
+def lane_params(cfg, mach):
+    """``(8, C * M)`` int32 lane parameters on the host, lane ``c * M +
+    m`` for config ``c`` and machine ``m``: capacity, policy and
+    alloc-no-fetch per config, the three latencies per machine, and two
+    rows of zeros (a lane block is 8 sublanes)."""
+    capacity, policy, anf = (np.asarray(x).astype(np.int32) for x in cfg)
+    l1_hit, uop_hit, mem_lat = (np.asarray(x).astype(np.int32)
+                                for x in mach)
+    n_cfg, n_mach = len(capacity), len(mem_lat)
+    zero = np.zeros(n_cfg * n_mach, np.int32)
+    return np.stack(
+        [np.repeat(x, n_mach) for x in (capacity, policy, anf)]
+        + [np.tile(x, n_cfg) for x in (l1_hit, uop_hit, mem_lat)]
+        + [zero, zero])
+
+
+def scan_grid(l1_sets, l1_ways, slots_used, track_ab, n_mach, rows, sums,
+              spill0s, lanes, *, interpret=False):
+    """``_run_grid``'s scan as one kernel, on :func:`pack`'s row words and
+    trace sums and :func:`lane_params`' lanes: the same counters, stacked
+    as ``(3, P, C, M, 12)`` ``ctr``, ``ctrA`` and ``ctrB``.  Traceable;
+    the caller jits."""
+    n_prog, n_lanes = spill0s.shape[0], lanes.shape[1]
+    n_cfg = n_lanes // n_mach
     lane_blocks = pl.cdiv(n_lanes, LANES)
     pad = lane_blocks * LANES - n_lanes
-
-    def lanes(x, per_cfg, fill):
-        x = x.astype(jnp.int32)
-        x = jnp.repeat(x, n_mach) if per_cfg else jnp.tile(x, n_cfg)
-        return jnp.pad(x, (0, pad), constant_values=fill)
-
-    # Padding lanes get the full VRF: they never touch the cVRF state.
-    lane_params = jnp.stack(
-        [lanes(capacity, True, isa.NUM_ARCH_VREGS), lanes(policy, True, 0),
-         lanes(anf, True, 0), lanes(l1_hit, False, 0),
-         lanes(uop_hit, False, 0), lanes(mem_lat, False, 0)]
-        + [jnp.zeros(lane_blocks * LANES, jnp.int32)] * 2)
-
-    rows = _pack_rows(arrays)
-    n_prog, n_rows = rows.shape[:2]
-    row_blocks = pl.cdiv(n_rows, ROW_BLOCK)
-    # Rows past the trace are all-inactive: they change nothing.
-    rows = jnp.pad(rows, ((0, 0), (0, row_blocks * ROW_BLOCK - n_rows),
-                          (0, 0)))
-    rows = rows.reshape(-1)
+    if pad:
+        # Padding lanes get the full VRF: they never touch the cVRF state.
+        fill = np.zeros((8, pad), np.int32)
+        fill[_CAP] = isa.NUM_ARCH_VREGS
+        lanes = jnp.concatenate([lanes, fill], axis=1)
+    row_blocks = rows.shape[0] // (n_prog * ROW_BLOCK * NUM_FIELDS)
     n_acc = 3 if track_ab else 1
 
     kernel = functools.partial(
@@ -391,23 +422,20 @@ def scan_grid(l1_sets, l1_ways, slots_used, track_ab, arrays, spill0s, cfg,
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         name="dispersion_scan",
         interpret=interpret,
-    )(spill0s.astype(jnp.int32), rows, lane_params)
+    )(spill0s, rows, lanes)
 
     lane_out = lane_out[:, :, :n_lanes].reshape(
         n_prog, n_acc, 8, n_cfg, n_mach)
-    lane_out = jnp.moveaxis(lane_out, 2, -1)          # (P, A, C, M, 8)
-    weights = arrays[-3:] if track_ab else arrays[-3:-2]
-    outs = []
-    for a, w in enumerate(weights):
-        tr = _trace_counters(arrays, slots_used, w)[:, None, None, :]
-        tr = jnp.broadcast_to(tr, (n_prog, n_cfg, n_mach, 5))
-        ln = lane_out[:, a]
-        # COUNTER_NAMES order: cycles, stall, vrf hits, vrf misses,
-        # spills, fills, l1 hits, l1 misses, reg reads, reg writes, mem
-        # reads, mem writes.
-        outs.append(jnp.concatenate(
-            [ln[..., :1] + tr[..., :1], ln[..., 1:], tr[..., 1:]], axis=-1))
+    lane_out = jnp.moveaxis(lane_out, (1, 2), (0, -1))   # (A, P, C, M, 8)
+    tr = jnp.broadcast_to(sums[:n_acc, :, None, None, :],
+                          (n_acc, n_prog, n_cfg, n_mach, 5))
+    # COUNTER_NAMES order: cycles, stall, vrf hits, vrf misses, spills,
+    # fills, l1 hits, l1 misses, reg reads, reg writes, mem reads, mem
+    # writes.
+    out = jnp.concatenate(
+        [lane_out[..., :1] + tr[..., :1], lane_out[..., 1:], tr[..., 1:]],
+        axis=-1)
     if not track_ab:
-        zero = jnp.zeros_like(outs[0])
-        outs += [zero, zero]
-    return tuple(outs)
+        out = jnp.concatenate(
+            [out, jnp.zeros((2,) + out.shape[1:], jnp.int32)])
+    return out

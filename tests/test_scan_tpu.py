@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from repro import obs, rvv
-from repro.core import policies, simulator
+from repro.core import policies, scan_tpu, simulator
 
 ALL_POLICIES = (policies.FIFO, policies.LRU, policies.LFU, policies.OPT)
 
@@ -63,7 +63,7 @@ def _case(case):
     return preps, sweep, machines
 
 
-def _device_args(preps, sweep, machines):
+def _xla_args(preps, sweep, machines):
     arrays, spill0s, slots_used = simulator._stack(preps)
     cfg = tuple(jnp.asarray(x) for x in (sweep.capacity, sweep.policy,
                                          sweep.alloc_no_fetch))
@@ -76,12 +76,27 @@ def _device_args(preps, sweep, machines):
             cfg, mach)
 
 
-@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
+def _pallas_args(preps, sweep, machines):
+    rows, sums = scan_tpu.pack([simulator._columns(p) for p in preps],
+                               simulator._FILLS,
+                               max(simulator._bucket(p.num_rows)
+                                   for p in preps))
+    lanes = scan_tpu.lane_params(
+        (sweep.capacity, sweep.policy, sweep.alloc_no_fetch),
+        (machines.l1_hit_cycles, machines.uop_hit_cycles,
+         machines.mem_latency))
+    spill0s = np.asarray([p.spill_line0 for p in preps], np.int32)
+    return (machines.l1_sets, machines.l1_ways, simulator._slots_used(preps),
+            any(p.num_folds for p in preps), len(machines),
+            jnp.asarray(rows), jnp.asarray(sums), jnp.asarray(spill0s),
+            jnp.asarray(lanes))
+
+
 @pytest.mark.parametrize("name", sorted(CASES))
 def test_kernel_matches_the_xla_scan(name):
     preps, sweep, machines = _case(CASES[name])
-    want = simulator._run_grid(*_device_args(preps, sweep, machines))
-    got = simulator._run_grid_pallas(*_device_args(preps, sweep, machines),
+    want = simulator._run_grid(*_xla_args(preps, sweep, machines))
+    got = simulator._run_grid_pallas(*_pallas_args(preps, sweep, machines),
                                      interpret=True)
     for label, w, g in zip(("ctr", "ctrA", "ctrB"), want, got):
         w, g = np.asarray(w), np.asarray(g)
@@ -96,7 +111,6 @@ def test_kernel_matches_the_xla_scan(name):
         assert np.asarray(want[1]).any()      # the periods were tracked
 
 
-@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
 @pytest.mark.parametrize("fused", [False, True])
 def test_simulate_grid_switches_to_the_kernel(monkeypatch, fused):
     """Where ``scan_impl`` says ``pallas``, both the fused and the
@@ -129,30 +143,26 @@ def test_simulate_grid_switches_to_the_kernel(monkeypatch, fused):
     assert simulator.compile_count() == c0
 
 
-@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
 def test_kernel_compiles_once_per_signature():
     """The kernel's jitted wrapper counts one compile per new (geometry,
     slots, track_ab, bucket) signature, and none for new latencies."""
     preps, sweep, machines = _case(dict(
         GRID, programs=[("gemv",)], geometry=(32, 2)))
     c0 = simulator.compile_count()
-    simulator._run_grid_pallas(*_device_args(preps, sweep, machines),
+    simulator._run_grid_pallas(*_pallas_args(preps, sweep, machines),
                                interpret=True)
     assert simulator.compile_count() == c0 + 1
     machines.mem_latency = machines.mem_latency + 3
-    simulator._run_grid_pallas(*_device_args(preps, sweep, machines),
+    simulator._run_grid_pallas(*_pallas_args(preps, sweep, machines),
                                interpret=True)
     assert simulator.compile_count() == c0 + 1
 
 
 class _On:
-    """An array stand-in that lives on a device of ``platform``."""
+    """A device stand-in of ``platform``."""
 
     def __init__(self, platform):
         self.platform = platform
-
-    def devices(self):
-        return {self}
 
 
 @pytest.mark.parametrize("platform,sets,want", [
@@ -162,4 +172,4 @@ def test_scan_impl_follows_the_arrays_platform(platform, sets, want):
     """The kernel runs where the engine's arrays are on a TPU, unless the
     L1 planes would not fit the kernel's VMEM."""
     machines = simulator.MachineSweep.make([5], l1_sets=sets)
-    assert simulator.scan_impl(machines, (_On(platform),)) == want
+    assert simulator.scan_impl(machines, _On(platform)) == want

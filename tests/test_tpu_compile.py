@@ -24,7 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import rvv
 from repro.configs import get
-from repro.core import policies, simulator
+from repro.core import policies, scan_tpu, simulator
 from repro.kernels import dispersed_gemm, flash_attention
 from repro.models import get_model
 
@@ -85,7 +85,6 @@ def test_flash_attention_compiles_at_phi3_attention_shape(one_chip):
         q, q, q)
 
 
-@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
 def test_engine_bucket_compiles(one_chip):
     """The fused (program, config, machine) scan at one bucket: the paper's
     Table 2 kernel count, the smoke test's capacity x policy grid."""
@@ -124,25 +123,21 @@ SCAN_DISPATCHES = {
 }
 
 
-@pytest.mark.filterwarnings("ignore:Some donated buffers were not usable")
 @pytest.mark.parametrize("name", sorted(SCAN_DISPATCHES))
 def test_scan_kernel_compiles(one_chip, name):
-    """The single-core scan as one Pallas kernel: its SMEM trace blocks
-    and VMEM state fit what the chip's compiler allows."""
+    """The single-core scan as one Pallas kernel, on the host-packed row
+    words, trace sums and lane parameters: its SMEM trace blocks and VMEM
+    state fit what the chip's compiler allows."""
     programs, rows, configs, l1_sets, track_ab = SCAN_DISPATCHES[name]
-    b = rvv.BENCHMARKS["gemv"]
-    arrays, _, _ = simulator._stack(
-        [simulator.prepare(b.build(**b.reduced_params).program)])
 
-    def spec(shape, dtype=jnp.int32):
-        return _spec(one_chip, shape, dtype)
+    def spec(shape):
+        return _spec(one_chip, shape, jnp.int32)
 
     compiled = simulator._run_grid_pallas.lower(
-        l1_sets, 2, (True,) * 5, track_ab,
-        tuple(spec((programs, rows) + a.shape[2:], a.dtype) for a in arrays),
-        spec((programs,)),
-        (spec((configs,)), spec((configs,)), spec((configs,), jnp.bool_)),
-        tuple(spec((1,)) for _ in range(3))).compile()
+        l1_sets, 2, (True,) * 5, track_ab, 1,
+        spec((programs * rows * scan_tpu.NUM_FIELDS,)),
+        spec((3, programs, 5)), spec((programs,)),
+        spec((8, configs))).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
